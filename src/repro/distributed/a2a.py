@@ -20,7 +20,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def a2a(x, axis_name: str, *, split_axis: int, concat_axis: int):
@@ -54,14 +53,14 @@ def moe_dispatch_combine(mesh: Mesh, batch_axes, *, n_chunks: int = 1):
     g_spec = P(batch_axes or None, "model", None, None, None)
     e_spec = P(batch_axes or None, None, "model", None, None)
 
-    @partial(shard_map, mesh=mesh, in_specs=(g_spec,), out_specs=e_spec,
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(g_spec,), out_specs=e_spec,
+             check_vma=False)
     def dispatch(x):          # local: (B_l, G/16, Ee, C, D)
         return a2a_chunked(x, "model", split_axis=2, concat_axis=1,
                            n_chunks=n_chunks, chunk_axis=3)
 
-    @partial(shard_map, mesh=mesh, in_specs=(e_spec,), out_specs=g_spec,
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(e_spec,), out_specs=g_spec,
+             check_vma=False)
     def combine(y):           # local: (B_l, G, Ee/16, C, D)
         return a2a_chunked(y, "model", split_axis=1, concat_axis=2,
                            n_chunks=n_chunks, chunk_axis=3)

@@ -1,4 +1,6 @@
-"""jit'd wrapper: Pallas on TPU, interpret-mode (CPU validation) otherwise."""
+"""jit'd wrapper for the flash-attention forward kernel. It compiles for
+the TPU; a caller that wants the Pallas interpreter (CPU validation) passes
+``interpret=True``."""
 
 from __future__ import annotations
 
@@ -9,17 +11,11 @@ import jax
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+                    interpret: bool = False):
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
                                interpret=interpret)

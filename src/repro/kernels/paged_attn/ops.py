@@ -1,4 +1,5 @@
-"""jit'd wrapper for the paged-attention decode kernel."""
+"""jit'd wrapper for the paged-attention decode kernel (compiled unless the
+caller passes ``interpret=True``)."""
 
 from __future__ import annotations
 
@@ -9,14 +10,8 @@ import jax
 from repro.kernels.paged_attn.kernel import paged_attention as _kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
-                    interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+                    interpret: bool = False):
     return _kernel(q, k_pages, v_pages, page_table, lengths,
                    interpret=interpret)

@@ -4,16 +4,19 @@ The SSD duality splits the computation into a quadratic intra-chunk part
 (attention-like, MXU-friendly — this kernel) and a linear inter-chunk
 recurrence (tiny, done in jnp by the caller; see ops.py).
 
-Grid: (B, n_chunks). Per step the kernel computes, entirely in VMEM:
-    cs      = cumsum(dt ⊙ A)                     (cl, nh)
-    y_diag  = (C·Bᵀ ⊙ L) · (x·dt)                (cl, nh·hp)
-    states  = Bᵀ · (decay_out ⊙ x·dt)            (nh·hp, ns)
-    exp_cs, exp_total                            (cl, nh), (1, nh)
+Grid: (B·n_chunks, nh). Per step the kernel computes one head of one
+chunk, entirely in VMEM (rows are (1, cl) vectors over the chunk):
+    cs      = cumsum(dt ⊙ A)                     (1, cl)
+    y_diag  = (C·Bᵀ ⊙ L ⊙ dt) · x                (cl, hp)
+    states  = (xᵀ ⊙ dt ⊙ decay_out) · B          (hp, ns)
+    exp_cs                                       (1, cl)
 where L = exp(cs_i − cs_j) on the lower triangle.
 
-Block shapes: one whole chunk per grid step — (cl, nh·hp) x tiles with
-cl = 128–256 keeps the (cl × cl) score matrix and the state outer product
-inside VMEM.
+Layout is chosen for the TPU lowering: the cumsum is a triangular matmul,
+heads are a grid axis (never a lane slice), x arrives transposed to
+(hp, cl) so every matmul is a plain or NT contraction, and dt/cs stay
+row vectors. C·Bᵀ is shared by all heads of a chunk: it is computed at
+head 0 into VMEM scratch, which is why the head axis is sequential.
 """
 
 from __future__ import annotations
@@ -23,51 +26,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _ssd_chunk_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref,
-                      y_ref, st_ref, ecs_ref, etot_ref, *,
-                      cl: int, nh: int, hp: int, ns: int):
-    x = x_ref[0].astype(jnp.float32)              # (cl, nh*hp)
-    dt = dt_ref[0].astype(jnp.float32)            # (cl, nh)
-    A = -jnp.exp(A_ref[...].astype(jnp.float32))  # (1, nh)
-    Bm = B_ref[0].astype(jnp.float32)             # (cl, ns)
-    Cm = C_ref[0].astype(jnp.float32)             # (cl, ns)
-
-    dA = dt * A                                   # (cl, nh)
-    cs = jnp.cumsum(dA, axis=0)
-    xdt = x * jnp.repeat(dt, hp, axis=1)          # (cl, nh*hp)
-
-    # scores (cl, cl) shared across heads; per-head decay L
-    sc = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+def _ssd_chunk_kernel(xT_ref, dt_ref, A_ref, B_ref, C_ref,
+                      y_ref, st_ref, ecs_ref, sc_ref, *, cl: int):
     ii = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 1)
     tri = ii >= jj
+    Bm = B_ref[0].astype(jnp.float32)             # (cl, ns)
 
-    # y_diag: loop over heads (hp-wide tiles) to keep L per-head in VMEM
-    def head_body(h, y):
-        seg = cs[:, h][:, None] - cs[:, h][None, :]        # (cl, cl)
-        L = jnp.exp(jnp.where(tri, seg, -1e9))
-        att = sc * L
-        xh = jax.lax.dynamic_slice(xdt, (0, h * hp), (cl, hp))
-        yh = jax.lax.dot_general(att, xh, (((1,), (0,)), ((), ())),
+    @pl.when(pl.program_id(1) == 0)
+    def _scores():                                # C·Bᵀ, shared by heads
+        Cm = C_ref[0].astype(jnp.float32)
+        sc_ref[...] = jax.lax.dot_general(
+            Cm, Bm, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    xT = xT_ref[0, 0].astype(jnp.float32)         # (hp, cl)
+    dt = dt_ref[0, 0].astype(jnp.float32)         # (1, cl)
+    A = -jnp.exp(A_ref[0].astype(jnp.float32))    # (1, 1)
+    dA = dt * A                                   # (1, cl)
+
+    # cumsum along the chunk as triangular matmuls, once as a row
+    # (cs_row[i] = Σ_{j≤i} dA[j]) and once as a column
+    cs_row = jax.lax.dot_general(dA, jnp.where(ii <= jj, 1.0, 0.0),
+                                 (((1,), (0,)), ((), ())),
+                                 precision=_HI,
                                  preferred_element_type=jnp.float32)
-        return jax.lax.dynamic_update_slice(y, yh, (0, h * hp))
+    cs_col = jax.lax.dot_general(jnp.where(tri, 1.0, 0.0), dA,
+                                 (((1,), (1,)), ((), ())), precision=_HI,
+                                 preferred_element_type=jnp.float32)
 
-    y = jax.lax.fori_loop(0, nh, head_body,
-                          jnp.zeros((cl, nh * hp), jnp.float32))
-    y_ref[0] = y.astype(y_ref.dtype)
+    L = jnp.exp(jnp.where(tri, cs_col - cs_row, -1e9))        # (cl, cl)
+    att = sc_ref[...] * L * dt
+    y_ref[0, 0] = jax.lax.dot_general(
+        att, xT, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
-    # chunk state: states[h·hp+p, n] = Σ_j B[j,n] · decay_out[j,h] · xdt[j,h,p]
-    total = cs[-1:, :]                            # (1, nh)
-    dec_out = jnp.exp(total - cs)                 # (cl, nh)
-    xw = xdt * jnp.repeat(dec_out, hp, axis=1)    # (cl, nh*hp)
-    st = jax.lax.dot_general(xw, Bm, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    st_ref[0] = st.astype(st_ref.dtype)           # (nh*hp, ns)
-    ecs_ref[0] = jnp.exp(cs).astype(ecs_ref.dtype)
-    etot_ref[0] = jnp.exp(total).astype(etot_ref.dtype)
+    # chunk state: st[p, n] = Σ_j x[j,p] · dt[j] · exp(total − cs[j]) · B[j,n]
+    total = jnp.sum(dA, axis=1, keepdims=True)                 # (1, 1)
+    w = dt * jnp.exp(total - cs_row)                           # (1, cl)
+    st_ref[0, 0] = jax.lax.dot_general(
+        xT * w, Bm, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(st_ref.dtype)
+    ecs_ref[0, 0] = jnp.exp(cs_row).astype(ecs_ref.dtype)
 
 
 def ssd_chunk_call(x, dt, A_log, B_, C_, *, chunk: int,
@@ -85,41 +90,42 @@ def ssd_chunk_call(x, dt, A_log, B_, C_, *, chunk: int,
     cl = min(chunk, S)
     assert S % cl == 0
     nc = S // cl
+    G = B * nc
 
-    xf = x.reshape(B, nc, cl, nh * hp).reshape(B * nc, cl, nh * hp)
-    dtf = dt.reshape(B * nc, cl, nh)
-    Bf = B_.reshape(B * nc, cl, ns)
-    Cf = C_.reshape(B * nc, cl, ns)
-    A2 = A_log.reshape(1, nh)
+    xT = x.reshape(G, cl, nh, hp).transpose(0, 2, 3, 1)       # (G,nh,hp,cl)
+    dtr = dt.reshape(G, cl, nh).transpose(0, 2, 1)[:, :, None]  # (G,nh,1,cl)
+    Bf = B_.reshape(G, cl, ns)
+    Cf = C_.reshape(G, cl, ns)
+    A3 = A_log.reshape(nh, 1, 1)
 
-    kernel = functools.partial(_ssd_chunk_kernel, cl=cl, nh=nh, hp=hp,
-                               ns=ns)
-    y, st, ecs, etot = pl.pallas_call(
-        kernel,
-        grid=(B * nc,),
+    y, st, ecs = pl.pallas_call(
+        functools.partial(_ssd_chunk_kernel, cl=cl),
+        grid=(G, nh),
         in_specs=[
-            pl.BlockSpec((1, cl, nh * hp), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, cl, nh), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, nh), lambda g: (0, 0)),
-            pl.BlockSpec((1, cl, ns), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, cl, ns), lambda g: (g, 0, 0)),
+            pl.BlockSpec((1, 1, hp, cl), lambda g, h: (g, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, cl), lambda g, h: (g, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda g, h: (h, 0, 0)),
+            pl.BlockSpec((1, cl, ns), lambda g, h: (g, 0, 0)),
+            pl.BlockSpec((1, cl, ns), lambda g, h: (g, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, cl, nh * hp), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, nh * hp, ns), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, cl, nh), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, 1, nh), lambda g: (g, 0, 0)),
+            pl.BlockSpec((1, 1, cl, hp), lambda g, h: (g, h, 0, 0)),
+            pl.BlockSpec((1, 1, hp, ns), lambda g, h: (g, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, cl), lambda g, h: (g, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * nc, cl, nh * hp), jnp.float32),
-            jax.ShapeDtypeStruct((B * nc, nh * hp, ns), jnp.float32),
-            jax.ShapeDtypeStruct((B * nc, cl, nh), jnp.float32),
-            jax.ShapeDtypeStruct((B * nc, 1, nh), jnp.float32),
+            jax.ShapeDtypeStruct((G, nh, cl, hp), jnp.float32),
+            jax.ShapeDtypeStruct((G, nh, hp, ns), jnp.float32),
+            jax.ShapeDtypeStruct((G, nh, 1, cl), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((cl, cl), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xf, dtf, A2, Bf, Cf)
+    )(xT, dtr, A3, Bf, Cf)
 
-    return (y.reshape(B, nc, cl, nh, hp),
+    ecs = ecs[:, :, 0].reshape(B, nc, nh, cl).transpose(0, 1, 3, 2)
+    return (y.reshape(B, nc, nh, cl, hp).transpose(0, 1, 3, 2, 4),
             st.reshape(B, nc, nh, hp, ns),
-            ecs.reshape(B, nc, cl, nh),
-            etot.reshape(B, nc, nh))
+            ecs,
+            ecs[:, :, -1])                 # exp(total) = exp(cs[last])

@@ -1,4 +1,5 @@
-"""jit'd SSD wrapper: Pallas intra-chunk kernel + jnp inter-chunk scan."""
+"""jit'd SSD wrapper: Pallas intra-chunk kernel + jnp inter-chunk scan.
+The kernel compiles unless the caller passes ``interpret=True``."""
 
 from __future__ import annotations
 
@@ -10,17 +11,11 @@ import jax.numpy as jnp
 from repro.kernels.ssd_scan.kernel import ssd_chunk_call
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x, dt, A_log, B_, C_, D_, *, chunk: int = 256, state=None,
-        interpret: bool | None = None):
+        interpret: bool = False):
     """Full SSD = Pallas intra-chunk pieces + linear inter-chunk scan.
     Returns (y (B,S,nh,hp), final_state (B,nh,hp,ns))."""
-    if interpret is None:
-        interpret = not _on_tpu()
     B, S, nh, hp = x.shape
     ns = B_.shape[-1]
     cl = min(chunk, S)

@@ -10,14 +10,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat shim: ``jax.sharding.AxisType`` and the
-    ``axis_types=`` kwarg of ``jax.make_mesh`` only exist on newer jax;
-    older installs get the same (Auto-typed) mesh without the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """A mesh over ``jax.devices()`` whose axes are all Auto-typed."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,0 +1,108 @@
+"""Compile-only rehearsals for one TPU v5e chip, at real model widths.
+
+The chip is described, not attached: the TPU compiler refuses here what
+it would refuse on the chip (unsupported lowerings, tile misalignment,
+VMEM or HBM overflow), and nothing runs. Each kernel test asserts that
+the kernel survived into the compiled program as a Mosaic custom call.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.paged_attn.kernel import paged_attention
+from repro.kernels.ssd_scan.kernel import ssd_chunk_call
+from repro.launch.mesh import HBM_BYTES
+from repro.launch.steps import make_serve_step
+from repro.models import lm
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e, with the persistent compile cache
+    off (what is compiled for a described chip cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setitem(os.environ, "TPU_LOG_DIR",
+                   os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("arch,dtype", [
+    ("stablelm-1.6b", jnp.bfloat16),
+    ("stablelm-1.6b", jnp.float32),
+    ("qwen2-vl-2b", jnp.bfloat16),
+])
+def test_paged_attention_compiles(one_chip, arch, dtype):
+    cfg = get_config(arch)
+    B, page, nblk, pool = 8, 16, 64, 160
+    H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    c = _compile(paged_attention, one_chip,
+                 ((B, H, hd), dtype), ((pool, page, KH, hd), dtype),
+                 ((pool, page, KH, hd), dtype), ((B, nblk), jnp.int32),
+                 ((B,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    cfg = get_config("stablelm-1.6b")
+    B, S = 1, 2048
+    H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    c = _compile(flash_attention_fwd, one_chip,
+                 ((B, S, H, hd), jnp.bfloat16), ((B, S, KH, hd), jnp.bfloat16),
+                 ((B, S, KH, hd), jnp.bfloat16))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_ssd_chunk_compiles(one_chip):
+    cfg = get_config("mamba2-130m")
+    s = cfg.ssm
+    B, S = 2, 2 * s.chunk
+    nh, hp, ns = s.n_heads(cfg.d_model), s.headdim, s.d_state
+    c = _compile(lambda *a: ssd_chunk_call(*a, chunk=s.chunk), one_chip,
+                 ((B, S, nh, hp), jnp.float32), ((B, S, nh), jnp.float32),
+                 ((nh,), jnp.float32), ((B, S, ns), jnp.float32),
+                 ((B, S, ns), jnp.float32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_stablelm_serve_step_fits_one_chip(one_chip):
+    """The decode step chip_smoke.py serves (B=8, max_len=1024) at full
+    width fits one chip's HBM: arguments plus temporaries."""
+    cfg = get_config("stablelm-1.6b")
+    B, max_len = 8, 1024
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,   # noqa: E731
+                                           sharding=one_chip)
+    params = jax.tree_util.tree_map(place, lm.abstract_params(cfg))
+    cache = jax.tree_util.tree_map(place, lm.abstract_cache(cfg, max_len, B))
+    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    c = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, tokens, pos).compile()
+    m = c.memory_analysis()
+    need = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert need < HBM_BYTES, (m.argument_size_in_bytes, m.temp_size_in_bytes)

@@ -94,10 +94,15 @@ def test_paged_attention_sweep(B, H, KH, hd, page, nblk):
                                atol=3e-5, rtol=3e-5)
 
 
-def test_model_mamba_uses_kernel_equivalently():
+def test_model_mamba_uses_kernel_equivalently(monkeypatch):
     """cfg.use_pallas=True must give the same forward as the jnp path."""
+    import functools
     from repro.configs import get_smoke_config
+    from repro.kernels.ssd_scan import ops as ssd_ops
     from repro.models import lm
+    # the model calls the compiled kernel; on the CPU run it interpreted
+    monkeypatch.setattr(ssd_ops, "ssd",
+                        functools.partial(ssd_ops.ssd, interpret=True))
     cfg = get_smoke_config("mamba2-130m")
     key = jax.random.PRNGKey(0)
     params = lm.init_params(cfg, key)

@@ -171,6 +171,34 @@ def test_kv_pager_spill_and_restore():
             np.asarray(ref[blk].astype(jnp.float32)))
 
 
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and code sets no other directory;
+    without it the cache is the fixed .jax_cache/ at the checkout root."""
+    from repro.launch import compile_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == saved[keys[0]]
+        else:
+            root = compile_cache.CHECKOUT_CACHE.parent
+            assert (root / "chip_smoke.py").exists()
+            assert got == jax.config.jax_compilation_cache_dir == \
+                str(root / ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
 def test_gradient_compression_error_feedback():
     """EF must make the AVERAGE of compressed grads track the true grads:
     after N steps, sum(compressed) ~= sum(true) despite int8 rounding."""

@@ -9,8 +9,8 @@ One process, one chip, everything made from ``--seed``:
 * phase A — stablelm-1.6b at full width (random weights) behind
   ``ServeLoop``: 8 prompts of 512 tokens, 32 new tokens each. One
   ``lm.decode_step`` is checked against ``lm.forward`` over the same
-  prefix; compile seconds, steady decode time and peak device memory
-  are printed as bring-up observations;
+  prefix; compile seconds and peak device memory are printed as bring-up
+  observations;
 * phase B — the pager→kernel seam at this model's cache geometry: layer
   0 of phase A's prefill cache goes through a ``KVPager`` too small to
   hold it (pages spill to the host and NVMe tiers and refault), and the
@@ -174,22 +174,6 @@ def phase_a(comp, cfg, seed):
     check(bool(jnp.all((out >= 0) & (out < cfg.vocab_size))),
           "generated token ids in [0, vocab)")
 
-    # steady decode: the compiled serve step on a cache of the same
-    # shape, one step (one token for each of the batch) at a time
-    cache = lm.init_cache(cfg, MAX_LEN, BATCH)
-    nxt = out[:, -1:]
-    n0 = comp.count
-    times = []
-    for i in range(16):
-        (nxt, cache), t, _, _ = comp.timed(sv.step, params, cache, nxt,
-                                           jnp.int32(PROMPT + NEW + i))
-        times.append(t)
-    steady = times[2:]
-    say(f"  decode steady: ms_per_step median={np.median(steady) * 1e3} "
-        f"min={min(steady) * 1e3} max={max(steady) * 1e3} "
-        f"(n={len(steady)}; one step = one token for each of {BATCH} "
-        f"sequences) compiles_in_window={comp.count - n0}")
-    del cache
     stats = jax.devices()[0].memory_stats() or {}
     peak = stats.get("peak_bytes_in_use", "not reported")
     say(f"  peak_bytes_in_use={peak}")

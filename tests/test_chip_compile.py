@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.paged_attn.kernel import paged_attention
+from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.ssd_scan.kernel import ssd_chunk_call
 from repro.launch.mesh import HBM_BYTES
 from repro.launch.steps import make_serve_step
@@ -88,6 +89,23 @@ def test_ssd_chunk_compiles(one_chip):
                  ((nh,), jnp.float32), ((B, S, ns), jnp.float32),
                  ((B, S, ns), jnp.float32))
     assert "tpu_custom_call" in c.as_text()
+
+
+def test_ssd_kernel_keeps_the_name_its_roofline_reads(one_chip):
+    """The compiled ``ops.ssd`` at mamba2-130m's sizes holds a Pallas call
+    that the trace reduction names as ``ssd_scan_roofline`` expects."""
+    from chipbench import tracing
+    from chipbench.spec import load_module
+    cfg = get_config("mamba2-130m")
+    s = cfg.ssm
+    B, S = 8, 2 * s.chunk
+    nh, hp, ns = s.n_heads(cfg.d_model), s.headdim, s.d_state
+    args = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+            for shape in ((B, S, nh, hp), (B, S, nh), (nh,), (B, S, ns),
+                          (B, S, ns), (nh,))]
+    text = ssd_ops.ssd.lower(*args, chunk=s.chunk).compile().as_text()
+    names = {tracing.kernel_name(line.strip()) for line in text.splitlines()}
+    assert load_module("metrics", "ssd_scan_roofline").KERNEL in names
 
 
 def test_stablelm_serve_step_fits_one_chip(one_chip):

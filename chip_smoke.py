@@ -164,7 +164,8 @@ def phase_a(comp, cfg, seed):
                       atol=LOGIT_ATOL, rtol=LOGIT_RTOL),
           f"decode_step matches forward (atol={LOGIT_ATOL}, "
           f"rtol={LOGIT_RTOL})")
-    k0, v0 = pcache["k"][0], pcache["v"][0]        # (B, S, KH, hd)
+    k0, v0 = (lm.kv_by_position(pcache[n][0], cfg.n_kv_heads)
+              for n in ("k", "v"))                  # (B, S, KH, hd)
     del pcache, dec
 
     out, t4, c4, n4 = comp.timed(sv.generate, prompts, NEW)

@@ -325,9 +325,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
     qg = q.reshape(B, KH, G, hd)
-    # keep the cache in bf16: casting it to f32 here gets HOISTED out of
-    # the layer scan by XLA, materializing the entire (L,B,S,KH,hd) cache
-    # in fp32 (6 GiB for musicgen decode_32k). MXU accumulates in f32 via
+    # keep the cache in bf16: a cast to f32 here would double the bytes
+    # the contractions read. MXU accumulates in f32 via
     # preferred_element_type.
     s = jnp.einsum("bkgd,bskd->bkgs", qg.astype(k_cache.dtype), k_cache,
                    preferred_element_type=jnp.float32) * scale

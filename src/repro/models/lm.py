@@ -391,10 +391,11 @@ def prefill_cache(cfg, caches, S: int) -> dict:
     out = {}
     win = cfg.swa_window
 
-    def ring(t):                       # t: (L,B,S,KH,hd)
+    def ring(t):                       # t: (L,B,S,KH,hd) -> (L,B,KH·hd,S)
         if win and t.shape[2] > win:
             t = t[:, :, -win:]
-        return t.astype(jnp.bfloat16)
+        L, B, S = t.shape[:3]
+        return jnp.moveaxis(t, 2, -1).reshape(L, B, -1, S).astype(jnp.bfloat16)
 
     fam = cfg.family
     if fam in ("ssm", "hybrid"):
@@ -423,21 +424,23 @@ def prefill_cache(cfg, caches, S: int) -> dict:
 # ---------------------------------------------------------------------------
 
 def cache_spec_defs(cfg, max_len: int, batch: int) -> dict:
-    """Declarative cache layout → ParamDefs (reuse abstract/specs helpers)."""
+    """Declarative cache layout → ParamDefs (reuse abstract/specs helpers).
+
+    A K or V cache is (layers, B, KH·hd, S): the sequence is the minor
+    axis, which is how the prefill's attention leaves it and how decode's
+    score and value contractions read it, so neither relays it out."""
     dt = "bfloat16"
-    H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     L = cfg.n_layers
     fam = cfg.family
     win = cfg.swa_window
     S = min(max_len, win) if win else max_len
     defs: Dict[str, Any] = {}
+
+    def kv(n):
+        return ParamDef((n, batch, cfg.n_kv_heads * cfg.hd, S),
+                        ("layers", "batch", "kv_heads", "kv_seq"), dtype=dt)
     if fam in ("dense", "vlm", "audio") or (fam == "moe" and cfg.mla is None):
-        defs["k"] = ParamDef((L, batch, S, KH, hd),
-                             ("layers", "batch", "kv_seq", "kv_heads", None),
-                             dtype=dt)
-        defs["v"] = ParamDef((L, batch, S, KH, hd),
-                             ("layers", "batch", "kv_seq", "kv_heads", None),
-                             dtype=dt)
+        defs["k"] = defs["v"] = kv(L)
     elif fam == "moe":                     # MLA: compressed latent cache
         m = cfg.mla
         defs["ckv"] = ParamDef((L, batch, S, m.kv_lora_rank),
@@ -460,13 +463,7 @@ def cache_spec_defs(cfg, max_len: int, batch: int) -> dict:
                                   ("layers", "batch", None, "ssm_state"),
                                   dtype=dt)
     if fam == "hybrid":
-        G = cfg.n_layers // cfg.attn_every
-        defs["k"] = ParamDef((G, batch, S, KH, hd),
-                             ("layers", "batch", "kv_seq", "kv_heads", None),
-                             dtype=dt)
-        defs["v"] = ParamDef((G, batch, S, KH, hd),
-                             ("layers", "batch", "kv_seq", "kv_heads", None),
-                             dtype=dt)
+        defs["k"] = defs["v"] = kv(cfg.n_layers // cfg.attn_every)
     return defs
 
 
@@ -484,15 +481,21 @@ def cache_specs(cfg, max_len, batch, mesh, rules=None):
     return specs(cache_spec_defs(cfg, max_len, batch), mesh, rules)
 
 
-def _decode_attn_block(cfg, p, x, kc, vc, pos, cos, sin, dtype):
-    """x: (B,1,D); kc/vc: (B,S,KH,hd). Returns (x', kc', vc')."""
+def kv_by_position(c, n_kv_heads):
+    """One layer's K or V cache, (B, KH·hd, S), viewed as (B, S, KH, hd)
+    (a transpose that XLA folds into the contraction reading it)."""
+    B, E, S = c.shape
+    return jnp.moveaxis(c.reshape(B, n_kv_heads, E // n_kv_heads, S), -1, 1)
+
+
+def _decode_attn_block(cfg, p, x, kc, vc, layer, pos, cos, sin, dtype):
+    """x: (B,1,D); kc/vc: the stacked (n,B,KH·hd,S) caches, of which index
+    ``layer`` is this block's. Writes the token's K/V into it in place and
+    attends over it. Returns (x', kc', vc')."""
     B = x.shape[0]
     H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    S = kc.shape[-1]
     win = cfg.swa_window
-    # optimization_barrier: stops XLA:CPU from hoisting a bf16->f32
-    # convert of the WHOLE stacked cache out of the layer scan (a 6 GiB
-    # phantom buffer; TPU's MXU consumes bf16 natively)
-    kc, vc = jax.lax.optimization_barrier((kc, vc))
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     pa = p["attn"]
     q = jnp.einsum("bsd,de->bse", h, pa["wq"].astype(dtype)).reshape(B, 1, H, hd)
@@ -501,11 +504,18 @@ def _decode_attn_block(cfg, p, x, kc, vc, pos, cos, sin, dtype):
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    idx = jnp.mod(pos, kc.shape[1]) if win else pos
-    kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, idx, 0, 0))
-    vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, idx, 0, 0))
-    o = attn.decode_attention(q[:, 0], kc.astype(dtype), vc.astype(dtype),
-                              pos, window=win)
+    idx = jnp.mod(pos, S) if win else pos
+
+    def write(c, t):                   # t (B,1,KH,hd) -> c[layer, :, :, idx]
+        col = t.reshape(1, B, KH * hd, 1).astype(c.dtype)
+        return jax.lax.dynamic_update_slice(c, col, (layer, 0, 0, idx))
+
+    def read(c):
+        c = jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+        return kv_by_position(c, KH).astype(dtype)
+
+    kc, vc = write(kc, k), write(vc, v)
+    o = attn.decode_attention(q[:, 0], read(kc), read(vc), pos, window=win)
     y = jnp.einsum("bhd,hdD->bD", o, pa["wo"].reshape(H, hd, cfg.d_model)
                    .astype(dtype))
     return x + y[:, None], kc, vc
@@ -572,7 +582,7 @@ def decode_step(cfg, params, cache, tokens, pos):
         sh = params["shared_attn"]
 
         def gbody(carry, xs):
-            p_g, stg, kc, vc = xs
+            (xc, kc, vc), (p_g, stg, g) = carry, xs
 
             def inner(c, ys):
                 p_l, st, cx, cb, cc = ys
@@ -580,14 +590,14 @@ def decode_step(cfg, params, cache, tokens, pos):
                                                        (cx, cb, cc), dtype)
                 return c + y, (st2,) + conv2
             xc, sts = jax.lax.scan(
-                inner, carry, (p_g, stg["ssm"], stg["conv_x"],
-                               stg["conv_b"], stg["conv_c"]))
-            xc, kc, vc = _decode_attn_block(cfg, sh, xc, kc, vc, pos,
+                inner, xc, (p_g, stg["ssm"], stg["conv_x"],
+                            stg["conv_b"], stg["conv_c"]))
+            xc, kc, vc = _decode_attn_block(cfg, sh, xc, kc, vc, g, pos,
                                             cos, sin, dtype)
             xc = _decode_ffn(cfg, sh, xc, dtype, moe_layer=False)
-            return xc, (sts, kc, vc)
-        x, ((st, cx, cb, cc), kc, vc) = jax.lax.scan(
-            gbody, x, (grouped, st_g, cache["k"], cache["v"]))
+            return (xc, kc, vc), sts
+        (x, kc, vc), (st, cx, cb, cc) = jax.lax.scan(
+            gbody, (x, cache["k"], cache["v"]), (grouped, st_g, jnp.arange(G)))
         resh = lambda a: a.reshape((cfg.n_layers,) + a.shape[2:])
         new_cache.update(ssm=resh(st), conv_x=resh(cx), conv_b=resh(cb),
                          conv_c=resh(cc), k=kc, v=vc)
@@ -617,13 +627,14 @@ def decode_step(cfg, params, cache, tokens, pos):
         moe_layer = fam == "moe"
 
         def body(carry, xs):
-            p_l, kc, vc = xs
-            xc, kc, vc = _decode_attn_block(cfg, p_l, carry, kc, vc, pos,
+            (xc, kc, vc), (p_l, layer) = carry, xs
+            xc, kc, vc = _decode_attn_block(cfg, p_l, xc, kc, vc, layer, pos,
                                             cos, sin, dtype)
             xc = _decode_ffn(cfg, p_l, xc, dtype, moe_layer=moe_layer)
-            return xc, (kc, vc)
-        x, (kc, vc) = jax.lax.scan(body, x, (params["layers"], cache["k"],
-                                             cache["v"]))
+            return (xc, kc, vc), None
+        (x, kc, vc), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(cache["k"].shape[0])))
         new_cache.update(k=kc, v=vc)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

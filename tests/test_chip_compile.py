@@ -6,7 +6,9 @@ VMEM or HBM overflow), and nothing runs. Each kernel test asserts that
 the kernel survived into the compiled program as a Mosaic custom call.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,9 +110,11 @@ def test_ssd_kernel_keeps_the_name_its_roofline_reads(one_chip):
     assert load_module("metrics", "ssd_scan_roofline").KERNEL in names
 
 
-def test_stablelm_serve_step_fits_one_chip(one_chip):
-    """The decode step chip_smoke.py serves (B=8, max_len=1024) at full
-    width fits one chip's HBM: arguments plus temporaries."""
+@pytest.fixture(scope="module")
+def stablelm_serve_step(one_chip):
+    """The decode step the benchmark serves for stablelm-1.6b (B=8,
+    max_len=1024, the cache donated), compiled at full width; with the
+    shape of its K cache."""
     cfg = get_config("stablelm-1.6b")
     B, max_len = 8, 1024
     place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,   # noqa: E731
@@ -121,6 +125,33 @@ def test_stablelm_serve_step_fits_one_chip(one_chip):
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     c = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
         params, cache, tokens, pos).compile()
+    return c, cache["k"].shape
+
+
+def test_stablelm_serve_step_fits_one_chip(stablelm_serve_step):
+    """The decode step chip_smoke.py serves (B=8, max_len=1024) at full
+    width fits one chip's HBM: arguments plus temporaries."""
+    c, _ = stablelm_serve_step
     m = c.memory_analysis()
     need = m.argument_size_in_bytes + m.temp_size_in_bytes
     assert need < HBM_BYTES, (m.argument_size_in_bytes, m.temp_size_in_bytes)
+
+
+def test_stablelm_serve_step_never_copies_the_kv_cache(stablelm_serve_step):
+    """The decode step reads the K/V cache where it lies and writes one
+    token into it in place: no copy or transpose in the compiled program
+    (fusions' bodies included) moves the whole stack or one layer of it,
+    in any shape, and the temporaries stay below the 3.92 GiB that such
+    copies took."""
+    c, shape = stablelm_serve_step
+    stack = math.prod(shape)
+    sizes = {stack, stack // shape[0]}
+    moved = []
+    for line in c.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
+                in sizes:
+            moved.append(line.strip()[:120])
+    assert not moved, moved
+    assert c.memory_analysis().temp_size_in_bytes < 3.92 * 2**30

@@ -155,6 +155,35 @@ def test_prefill_decode_consistency(arch):
             rtol=3e-2)
 
 
+@pytest.mark.parametrize("arch,window", [("stablelm-1.6b", 0),
+                                         ("zamba2-2.7b", 0),
+                                         ("mixtral-8x22b", 16)])
+def test_decode_step_writes_only_its_position(arch, window):
+    """decode_step at ``pos`` changes each layer's K/V cache at sequence
+    index ``pos`` (``pos % window`` in a ring) and nowhere else: every
+    other element is bit-identical to the input cache."""
+    cfg = get_smoke_config(arch).replace(swa_window=window)
+    key = jax.random.PRNGKey(5)
+    params = lm.init_params(cfg, key)
+    cache = lm.init_cache(cfg, max_len=24, batch=B)
+    for i, n in enumerate(("k", "v")):
+        cache[n] = jax.random.normal(jax.random.fold_in(key, i),
+                                     cache[n].shape).astype(cache[n].dtype)
+    S = cache["k"].shape[-1]
+    assert S == (window or 24)
+    for pos in (5, 22):
+        tok = jnp.full((B, 1), pos, jnp.int32)
+        _, new = lm.decode_step(cfg, params, cache, tok, jnp.int32(pos))
+        idx = pos % S if window else pos
+        others = np.arange(S) != idx
+        for n in ("k", "v"):
+            old = np.asarray(cache[n]).view(np.uint16)
+            upd = np.asarray(new[n]).view(np.uint16)
+            np.testing.assert_array_equal(upd[..., others], old[..., others])
+            written = (upd[..., idx] != old[..., idx]).any(axis=(1, 2))
+            assert written.all(), (n, pos, written)
+
+
 def test_moe_capacity_drops_are_bounded():
     from repro.models import moe as moe_mod
     cfg = get_smoke_config("mixtral-8x22b")

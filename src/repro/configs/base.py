@@ -67,6 +67,10 @@ class SSMConfig:
     headdim: int = 64
     chunk: int = 256
     d_conv: int = 4
+    ngroups: int = 1         # B/C groups: head h reads h // (heads/ngroups)
+    conv_bias: bool = False  # a bias on the depthwise convolution
+    pre_norm: bool = False   # an RMSNorm in front of the mixer
+    dt_min: float = 0.0      # dt clamped from below (0: not clamped)
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -90,12 +94,17 @@ class ModelConfig:
     swa_window: int = 0              # 0 = full attention; >0 = sliding window
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
-    mlp_kind: str = "swiglu"         # swiglu | gelu
+    mlp_kind: str = "swiglu"         # swiglu | gelu | geglu (gated, exact)
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
-    attn_every: int = 0              # hybrid: one (shared) attn block every k
-    shared_attn: bool = False        # hybrid: attn block weights are tied
+    # hybrid (Zamba2): the shared attention+MLP blocks run before the
+    # Mamba layers listed here, site k with block k % num_mem_blocks; each
+    # site has its own output linear and, with adapter_rank, its own
+    # low-rank adapter on the MLP's gate/up projection
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
     n_codebooks: int = 0             # audio: EnCodec codebooks (embed-sum)
     mrope_sections: Tuple[int, ...] = ()   # vlm: M-RoPE (t, h, w) dims
     # numerics / execution policy
@@ -128,6 +137,11 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(1, self.n_heads))
+
+    @property
+    def hybrid_sites(self) -> Tuple[int, ...]:
+        """The layers of this depth that a shared block precedes."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.n_layers)
 
     @property
     def is_attention_free(self) -> bool:
@@ -164,10 +178,12 @@ class ModelConfig:
         if self.family == "ssm":
             total += L * self._ssm_params()
         elif self.family == "hybrid":
-            n_attn = L // max(1, self.attn_every)
             total += L * self._ssm_params()
-            shared = per_attn + per_mlp_dense + 2 * d
-            total += shared if self.shared_attn else n_attn * shared
+            # attention reads [x, embedding]: q, k, v are 2d wide
+            shared = per_attn + d * self.n_heads * self.hd + per_mlp_dense
+            total += self.num_mem_blocks * (shared + 3 * d)
+            site = d * d + self.adapter_rank * (d + 2 * self.d_ff)
+            total += len(self.hybrid_sites) * site
         elif self.family == "moe":
             m = self.moe
             per_expert = self._mlp_params(m.d_ff_expert)
@@ -202,17 +218,17 @@ class ModelConfig:
     def _mlp_params(self, d_ff: int) -> int:
         if d_ff == 0:
             return 0
-        n_in = 2 if self.mlp_kind == "swiglu" else 1
+        n_in = 1 if self.mlp_kind == "gelu" else 2
         return (n_in + 1) * self.d_model * d_ff
 
     def _ssm_params(self) -> int:
         s = self.ssm
         d, di, ns = self.d_model, s.d_inner(self.d_model), s.d_state
-        nh = s.n_heads(d)
-        in_proj = d * (2 * di + 2 * ns + nh)   # [z, x, B, C, dt]
-        conv = s.d_conv * (di + 2 * ns)
+        nh, gns = s.n_heads(d), s.ngroups * ns
+        in_proj = d * (2 * di + 2 * gns + nh)  # [z, x, B, C, dt]
+        conv = (s.d_conv + s.conv_bias) * (di + 2 * gns)
         out = di * d
-        extra = 2 * nh + di                    # A_log, D, norm
+        extra = 2 * nh + di + s.pre_norm * d   # A_log, D, norms
         return in_proj + conv + out + extra
 
 
@@ -255,7 +271,7 @@ def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
 
 
 def cells(include_skipped: bool = False):
-    """All (arch, shape) cells; 40 total, with skip annotations."""
+    """All (arch, shape) cells; 44 total, with skip annotations."""
     _ensure_loaded()
     out = []
     for a in list_archs():
@@ -272,6 +288,7 @@ def _ensure_loaded():
         return
     from repro.configs import (  # noqa: F401
         granite_34b, yi_34b, deepseek_67b, stablelm_1_6b,
-        deepseek_v2_lite_16b, mixtral_8x22b, zamba2_2_7b, mamba2_130m,
+        deepseek_v2_lite_16b, mixtral_8x22b, zamba2_2_7b, zamba2_7b,
+        mamba2_130m,
         qwen2_vl_2b, musicgen_large,
     )

@@ -15,8 +15,9 @@ where L = exp(cs_i − cs_j) on the lower triangle.
 Layout is chosen for the TPU lowering: the cumsum is a triangular matmul,
 heads are a grid axis (never a lane slice), x arrives transposed to
 (hp, cl) so every matmul is a plain or NT contraction, and dt/cs stay
-row vectors. C·Bᵀ is shared by all heads of a chunk: it is computed at
-head 0 into VMEM scratch, which is why the head axis is sequential.
+row vectors. C·Bᵀ is shared by the heads of a group (all heads of a
+chunk when B and C have one group): it is computed at the group's first
+head into VMEM scratch, which is why the head axis is sequential.
 """
 
 from __future__ import annotations
@@ -32,13 +33,15 @@ _HI = jax.lax.Precision.HIGHEST
 
 
 def _ssd_chunk_kernel(xT_ref, dt_ref, A_ref, B_ref, C_ref,
-                      y_ref, st_ref, ecs_ref, sc_ref, *, cl: int):
+                      y_ref, st_ref, ecs_ref, sc_ref, *, cl: int,
+                      group_heads: int):
     ii = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (cl, cl), 1)
     tri = ii >= jj
     Bm = B_ref[0].astype(jnp.float32)             # (cl, ns)
+    h = pl.program_id(1)                          # group_heads 0: one group
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(h == 0 if group_heads == 0 else h % group_heads == 0)
     def _scores():                                # C·Bᵀ, shared by heads
         Cm = C_ref[0].astype(jnp.float32)
         sc_ref[...] = jax.lax.dot_general(
@@ -77,7 +80,8 @@ def _ssd_chunk_kernel(xT_ref, dt_ref, A_ref, B_ref, C_ref,
 
 def ssd_chunk_call(x, dt, A_log, B_, C_, *, chunk: int,
                    interpret: bool = False):
-    """x: (B, S, nh, hp); dt: (B, S, nh); A_log: (nh,); B_/C_: (B, S, ns).
+    """x: (B, S, nh, hp); dt: (B, S, nh); A_log: (nh,); B_/C_: (B, S, ns),
+    or (B, S, G, ns) where head h reads group h // (nh/G).
 
     Returns per-chunk pieces:
       y_diag  (B, nc, cl, nh, hp)
@@ -94,19 +98,28 @@ def ssd_chunk_call(x, dt, A_log, B_, C_, *, chunk: int,
 
     xT = x.reshape(G, cl, nh, hp).transpose(0, 2, 3, 1)       # (G,nh,hp,cl)
     dtr = dt.reshape(G, cl, nh).transpose(0, 2, 1)[:, :, None]  # (G,nh,1,cl)
-    Bf = B_.reshape(G, cl, ns)
-    Cf = C_.reshape(G, cl, ns)
+    if B_.ndim == 3:
+        group_heads = 0
+        Bf = B_.reshape(G, cl, ns)
+        Cf = C_.reshape(G, cl, ns)
+        bc_index = lambda g, h: (g, 0, 0)                      # noqa: E731
+    else:                      # (G·ngroups, cl, ns): chunk-major, then group
+        ng = B_.shape[2]
+        group_heads = nh // ng
+        Bf, Cf = (t.reshape(G, cl, ng, ns).transpose(0, 2, 1, 3)
+                  .reshape(G * ng, cl, ns) for t in (B_, C_))
+        bc_index = lambda g, h: (g * ng + h // group_heads, 0, 0)  # noqa
     A3 = A_log.reshape(nh, 1, 1)
 
     y, st, ecs = pl.pallas_call(
-        functools.partial(_ssd_chunk_kernel, cl=cl),
+        functools.partial(_ssd_chunk_kernel, cl=cl, group_heads=group_heads),
         grid=(G, nh),
         in_specs=[
             pl.BlockSpec((1, 1, hp, cl), lambda g, h: (g, h, 0, 0)),
             pl.BlockSpec((1, 1, 1, cl), lambda g, h: (g, h, 0, 0)),
             pl.BlockSpec((1, 1, 1), lambda g, h: (h, 0, 0)),
-            pl.BlockSpec((1, cl, ns), lambda g, h: (g, 0, 0)),
-            pl.BlockSpec((1, cl, ns), lambda g, h: (g, 0, 0)),
+            pl.BlockSpec((1, cl, ns), bc_index),
+            pl.BlockSpec((1, cl, ns), bc_index),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, cl, hp), lambda g, h: (g, h, 0, 0)),

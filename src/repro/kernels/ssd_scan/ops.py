@@ -15,6 +15,7 @@ from repro.kernels.ssd_scan.kernel import ssd_chunk_call
 def ssd(x, dt, A_log, B_, C_, D_, *, chunk: int = 256, state=None,
         interpret: bool = False):
     """Full SSD = Pallas intra-chunk pieces + linear inter-chunk scan.
+    B_/C_: (B,S,ns), or (B,S,G,ns) where head h reads group h // (nh/G).
     Returns (y (B,S,nh,hp), final_state (B,nh,hp,ns))."""
     B, S, nh, hp = x.shape
     ns = B_.shape[-1]
@@ -24,8 +25,9 @@ def ssd(x, dt, A_log, B_, C_, D_, *, chunk: int = 256, state=None,
         pad = cl - S % cl
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        B_ = jnp.pad(B_, ((0, 0), (0, pad), (0, 0)))
-        C_ = jnp.pad(C_, ((0, 0), (0, pad), (0, 0)))
+        bc_pad = ((0, 0), (0, pad)) + ((0, 0),) * (B_.ndim - 2)
+        B_ = jnp.pad(B_, bc_pad)
+        C_ = jnp.pad(C_, bc_pad)
         S = S + pad
     nc = S // cl
 
@@ -35,15 +37,25 @@ def ssd(x, dt, A_log, B_, C_, D_, *, chunk: int = 256, state=None,
     if state is None:
         state = jnp.zeros((B, nh, hp, ns), jnp.float32)
 
-    C_c = jnp.moveaxis(C_.reshape(B, nc, cl, ns), 1, 0).astype(jnp.float32)
+    C_c = jnp.moveaxis(C_.reshape((B, nc, cl) + C_.shape[2:]), 1,
+                       0).astype(jnp.float32)
     sc = jnp.moveaxis(states, 1, 0)
     ec = jnp.moveaxis(exp_cs, 1, 0)
     et = jnp.moveaxis(exp_tot, 1, 0)
 
+    def off_diagonal(C_k, st, ecs_k):
+        if C_k.ndim == 3:
+            return jnp.einsum("bin,bhpn,bih->bihp", C_k, st, ecs_k)
+        g = C_k.shape[2]                      # heads split into their groups
+        st = st.reshape((B, g, nh // g) + st.shape[2:])
+        ecs_k = ecs_k.reshape(B, cl, g, nh // g)
+        y = jnp.einsum("bign,bgkpn,bigk->bigkp", C_k, st, ecs_k)
+        return y.reshape(B, cl, nh, hp)
+
     def step(carry, inp):
         st = carry
         C_k, st_k, ecs_k, etot_k = inp
-        y_off = jnp.einsum("bin,bhpn,bih->bihp", C_k, st, ecs_k)
+        y_off = off_diagonal(C_k, st, ecs_k)
         st = st * etot_k[:, :, None, None] + st_k
         return st, y_off
 

@@ -93,7 +93,7 @@ def mlp_defs(cfg, d_ff: int, prefix_logical_in="embed", ll=()) -> dict:
     """Param defs for one MLP; ``ll`` prepends stacked-layer axes."""
     d = cfg.d_model
     Lax = tuple("layers" for _ in ll)
-    if cfg.mlp_kind == "swiglu":
+    if cfg.mlp_kind in ("swiglu", "geglu"):     # gated: w1 gate, w3 up
         return {
             "w1": ParamDef(ll + (d, d_ff), Lax + ("embed", "mlp")),
             "w3": ParamDef(ll + (d, d_ff), Lax + ("embed", "mlp")),

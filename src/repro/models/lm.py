@@ -9,7 +9,7 @@ HLO stays compact for 88–95-layer archs.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -70,6 +70,35 @@ def _block_defs(cfg, ll=(), *, moe_layer: bool) -> dict:
     return out
 
 
+def _shared_block_defs(cfg) -> dict:
+    """A Zamba2 shared block: attention over [x, embedding] (2·d wide),
+    then the gated GELU MLP."""
+    d, H, KH, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    return {
+        "ln_in": ParamDef((2 * d,), ("embed",), init="ones"),
+        "attn": {
+            "wq": ParamDef((2 * d, H * hd), ("embed", "heads")),
+            "wk": ParamDef((2 * d, KH * hd), ("embed", "kv_heads")),
+            "wv": ParamDef((2 * d, KH * hd), ("embed", "kv_heads")),
+            "wo": ParamDef((H * hd, d), ("heads", "embed")),
+        },
+        "ln_ff": ParamDef((d,), ("embed",), init="ones"),
+        "mlp": mlp_defs(cfg, cfg.d_ff),
+    }
+
+
+def _site_defs(cfg) -> dict:
+    """What one site of a shared block holds alone: the linear into the
+    next Mamba layer and the adapter of the MLP's gate/up projection."""
+    d, r, ff = cfg.d_model, cfg.adapter_rank, cfg.d_ff
+    out = {"linear": ParamDef((d, d), ("embed", None))}
+    if r:
+        out["adapter_in"] = ParamDef((d, r), ("embed", None))
+        out["adapter_g"] = ParamDef((r, ff), (None, "mlp"))
+        out["adapter_u"] = ParamDef((r, ff), (None, "mlp"))
+    return out
+
+
 def param_defs(cfg) -> dict:
     d = cfg.d_model
     V = padded_vocab(cfg.vocab_size)
@@ -94,7 +123,9 @@ def param_defs(cfg) -> dict:
         defs["layers"] = mam.mamba_defs(cfg, ll=(L,))
     elif fam == "hybrid":
         defs["layers"] = mam.mamba_defs(cfg, ll=(L,))
-        defs["shared_attn"] = _block_defs(cfg, (), moe_layer=False)
+        defs["shared"] = [_shared_block_defs(cfg)
+                          for _ in range(cfg.num_mem_blocks)]
+        defs["sites"] = [_site_defs(cfg) for _ in cfg.hybrid_sites]
     elif fam == "moe":
         fk = cfg.moe.first_k_dense
         if fk:
@@ -266,6 +297,119 @@ def _scan_blocks(cfg, stacked, x, cos, sin, dtype, *, moe_layer,
 
 
 # ---------------------------------------------------------------------------
+# Zamba2 hybrid stack (train / prefill / decode)
+# ---------------------------------------------------------------------------
+
+def _site_scale(cfg) -> float:
+    """Zamba2 scales its attention scores by (head_dim / 2) ** -0.5."""
+    return (cfg.hd / 2) ** -0.5
+
+
+def _site_attn_prefill(cfg, blk, h, cos, sin, dtype):
+    """Causal attention of a shared block over h = norm([x, e]) (B,S,2D).
+    Returns (its output (B,S,D), this site's (k, v))."""
+    B, S, _ = h.shape
+    H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    pa = blk["attn"]
+    q, k, v = (jnp.einsum("bsd,de->bse", h, pa[w].astype(dtype))
+               .reshape(B, S, n, hd)
+               for w, n in (("wq", H), ("wk", KH), ("wv", KH)))
+    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    o = attn.flash_attention(q, k, v, causal=True, q_chunk=cfg.attn_q_chunk,
+                             scale=_site_scale(cfg),
+                             schedule=cfg.attn_schedule)
+    y = jnp.einsum("bshd,hdD->bsD",
+                   o, pa["wo"].reshape(H, hd, cfg.d_model).astype(dtype))
+    return y, (k, v)
+
+
+def _site_mlp(cfg, blk, site, y, dtype):
+    """down(gelu(g) * u) with [g, u] = W·h + B_s(A_s·h), h = norm(y): the
+    shared block's MLP with the site's adapter (exact, erf GELU)."""
+    h = rms_norm(y, blk["ln_ff"], cfg.norm_eps)
+    m = blk["mlp"]
+    g = jnp.einsum("bsd,df->bsf", h, m["w1"].astype(dtype))
+    u = jnp.einsum("bsd,df->bsf", h, m["w3"].astype(dtype))
+    if "adapter_in" in site:
+        a = jnp.einsum("bsd,dr->bsr", h, site["adapter_in"].astype(dtype))
+        g = g + jnp.einsum("bsr,rf->bsf", a, site["adapter_g"].astype(dtype))
+        u = u + jnp.einsum("bsr,rf->bsf", a, site["adapter_u"].astype(dtype))
+    return jnp.einsum("bsf,fd->bsd", jax.nn.gelu(g, approximate=False) * u,
+                      m["w2"].astype(dtype))
+
+
+@partial(jax.jit, static_argnames=("cfg", "dtype", "use_pallas"))
+def _hybrid_stack(cfg, params, x, states, kv, cos, sin, pos, dtype,
+                  use_pallas=False):
+    """The Zamba2 pattern over x (B,S,D), the embedding output. Before
+    Mamba layer i = hybrid_sites[k], shared block k % num_mem_blocks reads
+    [x, embedding] and the site's linear gives t; that layer then computes
+    x + Mamba(norm(x + t)), and every other layer x + Mamba(norm(x)).
+
+    ``states`` holds every layer's SSM and conv state (row i: layer i),
+    rewritten row by row in place. In prefill (``pos`` None) the layers
+    start from zero state and each site appends its (k, v) to ``kv``; in
+    decode they continue from ``states`` and the sites attend over their
+    rows of the caches ``kv`` = (k, v), writing the token at ``pos``. Runs
+    of Mamba layers between sites are loops over the stacked layers. One
+    jitted call, so that eager callers compile the stack once.
+    Returns (x, states, kv)."""
+    e = x
+    sites = cfg.hybrid_sites
+    names = ("ssm", "conv_x", "conv_b", "conv_c")
+    decode = pos is not None
+
+    def layer(i, x, t, states):
+        at = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,      # noqa
+                                                    keepdims=False)
+        p = jax.tree_util.tree_map(at, params["layers"])
+        st = conv = None
+        if decode:
+            st, conv = at(states["ssm"]), tuple(at(states[n])
+                                                for n in names[1:])
+        u = x if t is None else x + t
+        y, st, conv = mam.mamba_block(cfg, p, u, dtype, state=st,
+                                      conv_state=conv, return_state=True,
+                                      use_pallas=use_pallas)
+        new = dict(zip(names, (st,) + conv))
+        return x + y, {n: jax.lax.dynamic_update_index_in_dim(
+            a, new[n].astype(a.dtype), i, 0) for n, a in states.items()}
+
+    def site_attn(blk, h, k, kv):
+        if not decode:
+            y, kv_k = _site_attn_prefill(cfg, blk, h, cos, sin, dtype)
+            return y, kv + (kv_k,)
+        y, kc, vc = _decode_attn(cfg, blk["attn"], h, *kv, k, pos, cos, sin,
+                                 dtype, scale=_site_scale(cfg))
+        return y[:, None], (kc, vc)
+
+    starts = sorted({0, *sites})
+    for lo, hi in zip(starts, starts[1:] + [cfg.n_layers]):
+        t = None
+        if lo in sites:
+            k = sites.index(lo)
+            blk, site = params["shared"][k % cfg.num_mem_blocks], \
+                params["sites"][k]
+            with jax.named_scope("hybrid.shared"), \
+                    jax.named_scope(f"site{k}"):
+                h = rms_norm(jnp.concatenate([x, e], -1), blk["ln_in"],
+                             cfg.norm_eps)
+                y, kv = site_attn(blk, h, k, kv)
+                t = _site_mlp(cfg, blk, site, y, dtype)
+            with jax.named_scope("hybrid.site_linear"):
+                t = jnp.einsum("bsd,de->bse", t, site["linear"].astype(dtype))
+        with jax.named_scope("hybrid.mamba"):
+            if t is not None:
+                x, states = layer(lo, x, t, states)
+                lo += 1
+            if lo < hi:
+                x, states = jax.lax.fori_loop(
+                    lo, hi, lambda i, c: layer(i, c[0], None, c[1]),
+                    (x, states))
+    return x, states, kv
+
+
+# ---------------------------------------------------------------------------
 # Forward (train & prefill share this; prefill also returns the KV cache)
 # ---------------------------------------------------------------------------
 
@@ -325,36 +469,13 @@ def forward(cfg, params, batch, *, mesh=None, rules=None,
         caches["ssm"] = states
         caches["conv_x"], caches["conv_b"], caches["conv_c"] = convs
     elif fam == "hybrid":
-        k = cfg.attn_every
-        groups = cfg.n_layers // k
-        grouped = jax.tree_util.tree_map(
-            lambda a: a.reshape((groups, k) + a.shape[1:]), params["layers"])
-        sh = params["shared_attn"]
-
-        def group_body(carry, p_g):
-            xc = carry
-
-            def inner(c, p_l):
-                p_l = _cast_stacked(cfg, p_l, dtype)
-                y, st, conv = mam.mamba_block(cfg, p_l, _cstr(c), dtype,
-                                              return_state=True,
-                                              use_pallas=cfg.use_pallas,
-                                              mesh=mesh, rules=rules)
-                return c + y, (st, conv)
-            xc, (sts, convs) = jax.lax.scan(inner, xc, p_g)
-            xc, _, cache = _transformer_block(cfg, sh, xc, cos, sin, dtype,
-                                              moe_layer=False,
-                                              collect_cache=collect_cache,
-                                              mesh=mesh, rules=rules)
-            return xc, (sts, convs, cache)
-        group_body = _maybe_remat(group_body, cfg)
-        x, (states, convs, kv) = jax.lax.scan(group_body, x, grouped)
-        resh = lambda a: a.reshape((cfg.n_layers,) + a.shape[2:])
-        caches["ssm"] = resh(states)
-        caches["conv_x"], caches["conv_b"], caches["conv_c"] = \
-            (resh(cv) for cv in convs)
+        states = {n: jnp.zeros(sd.shape, sd.dtype) for n, sd in
+                  abstract_cache(cfg, S, B).items() if n not in ("k", "v")}
+        x, states, kv = _hybrid_stack(cfg, params, x, states, (), cos, sin,
+                                      None, dtype, use_pallas=cfg.use_pallas)
+        caches.update(states)
         if collect_cache:
-            caches["kv"] = kv
+            caches["kv"] = tuple(jnp.stack(t) for t in zip(*kv))
     elif fam == "moe":
         fk = cfg.moe.first_k_dense
         if fk:
@@ -450,25 +571,37 @@ def cache_spec_defs(cfg, max_len: int, batch: int) -> dict:
     if fam in ("ssm", "hybrid"):
         s = cfg.ssm
         di, nh, ns = s.d_inner(cfg.d_model), s.n_heads(cfg.d_model), s.d_state
+        gns = s.ngroups * ns
         hax = "ssm_heads" if nh % 16 == 0 else "ssm_heads_rep"
         defs["ssm"] = ParamDef((L, batch, nh, s.headdim, ns),
                                ("layers", "batch", hax, None, "ssm_state"),
                                dtype="float32")
         defs["conv_x"] = ParamDef((L, batch, s.d_conv - 1, di),
                                   ("layers", "batch", None, hax), dtype=dt)
-        defs["conv_b"] = ParamDef((L, batch, s.d_conv - 1, ns),
+        defs["conv_b"] = ParamDef((L, batch, s.d_conv - 1, gns),
                                   ("layers", "batch", None, "ssm_state"),
                                   dtype=dt)
-        defs["conv_c"] = ParamDef((L, batch, s.d_conv - 1, ns),
+        defs["conv_c"] = ParamDef((L, batch, s.d_conv - 1, gns),
                                   ("layers", "batch", None, "ssm_state"),
                                   dtype=dt)
     if fam == "hybrid":
-        defs["k"] = defs["v"] = kv(cfg.n_layers // cfg.attn_every)
+        defs["k"] = defs["v"] = kv(len(cfg.hybrid_sites))
     return defs
 
 
 def abstract_cache(cfg, max_len, batch):
     return abstract(cache_spec_defs(cfg, max_len, batch))
+
+
+@lru_cache(maxsize=None)
+def cache_bytes(cfg, max_len, batch) -> dict:
+    """Bytes of a batch's decode cache: attention K/V (or MLA latents) and
+    recurrent state (SSM state and convolution taps)."""
+    sizes = {n: math.prod(sd.shape) * sd.dtype.itemsize
+             for n, sd in abstract_cache(cfg, max_len, batch).items()}
+    state = sum(v for n, v in sizes.items() if n == "ssm"
+                or n.startswith("conv_"))
+    return {"kv_bytes": sum(sizes.values()) - state, "state_bytes": state}
 
 
 def init_cache(cfg, max_len, batch):
@@ -488,16 +621,15 @@ def kv_by_position(c, n_kv_heads):
     return jnp.moveaxis(c.reshape(B, n_kv_heads, E // n_kv_heads, S), -1, 1)
 
 
-def _decode_attn_block(cfg, p, x, kc, vc, layer, pos, cos, sin, dtype):
-    """x: (B,1,D); kc/vc: the stacked (n,B,KH·hd,S) caches, of which index
-    ``layer`` is this block's. Writes the token's K/V into it in place and
-    attends over it. Returns (x', kc', vc')."""
-    B = x.shape[0]
+def _decode_attn(cfg, pa, h, kc, vc, layer, pos, cos, sin, dtype,
+                 scale=None):
+    """h: (B,1,E) the normed input; kc/vc: the stacked (n,B,KH·hd,S)
+    caches, of which index ``layer`` is this block's. Writes the token's K/V
+    into it in place and attends over it. Returns (y (B,D), kc', vc')."""
+    B = h.shape[0]
     H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     S = kc.shape[-1]
     win = cfg.swa_window
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    pa = p["attn"]
     q = jnp.einsum("bsd,de->bse", h, pa["wq"].astype(dtype)).reshape(B, 1, H, hd)
     k = jnp.einsum("bsd,de->bse", h, pa["wk"].astype(dtype)).reshape(B, 1, KH, hd)
     v = jnp.einsum("bsd,de->bse", h, pa["wv"].astype(dtype)).reshape(B, 1, KH, hd)
@@ -515,9 +647,19 @@ def _decode_attn_block(cfg, p, x, kc, vc, layer, pos, cos, sin, dtype):
         return kv_by_position(c, KH).astype(dtype)
 
     kc, vc = write(kc, k), write(vc, v)
-    o = attn.decode_attention(q[:, 0], read(kc), read(vc), pos, window=win)
+    o = attn.decode_attention(q[:, 0], read(kc), read(vc), pos, window=win,
+                              scale=scale)
     y = jnp.einsum("bhd,hdD->bD", o, pa["wo"].reshape(H, hd, cfg.d_model)
                    .astype(dtype))
+    return y, kc, vc
+
+
+def _decode_attn_block(cfg, p, x, kc, vc, layer, pos, cos, sin, dtype):
+    """x: (B,1,D); the block's attention with its residual (see
+    ``_decode_attn``). Returns (x', kc', vc')."""
+    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    y, kc, vc = _decode_attn(cfg, p["attn"], h, kc, vc, layer, pos, cos, sin,
+                             dtype)
     return x + y[:, None], kc, vc
 
 
@@ -572,35 +714,11 @@ def decode_step(cfg, params, cache, tokens, pos):
                       cache["conv_b"], cache["conv_c"]))
         new_cache.update(ssm=st, conv_x=cx, conv_b=cb, conv_c=cc)
     elif fam == "hybrid":
-        k = cfg.attn_every
-        G = cfg.n_layers // k
-        grouped = jax.tree_util.tree_map(
-            lambda a: a.reshape((G, k) + a.shape[1:]), params["layers"])
-        st_g = jax.tree_util.tree_map(
-            lambda a: a.reshape((G, k) + a.shape[1:]),
-            {n: cache[n] for n in ("ssm", "conv_x", "conv_b", "conv_c")})
-        sh = params["shared_attn"]
-
-        def gbody(carry, xs):
-            (xc, kc, vc), (p_g, stg, g) = carry, xs
-
-            def inner(c, ys):
-                p_l, st, cx, cb, cc = ys
-                y, st2, conv2 = mam.mamba_decode_block(cfg, p_l, c, st,
-                                                       (cx, cb, cc), dtype)
-                return c + y, (st2,) + conv2
-            xc, sts = jax.lax.scan(
-                inner, xc, (p_g, stg["ssm"], stg["conv_x"],
-                            stg["conv_b"], stg["conv_c"]))
-            xc, kc, vc = _decode_attn_block(cfg, sh, xc, kc, vc, g, pos,
-                                            cos, sin, dtype)
-            xc = _decode_ffn(cfg, sh, xc, dtype, moe_layer=False)
-            return (xc, kc, vc), sts
-        (x, kc, vc), (st, cx, cb, cc) = jax.lax.scan(
-            gbody, (x, cache["k"], cache["v"]), (grouped, st_g, jnp.arange(G)))
-        resh = lambda a: a.reshape((cfg.n_layers,) + a.shape[2:])
-        new_cache.update(ssm=resh(st), conv_x=resh(cx), conv_b=resh(cb),
-                         conv_c=resh(cc), k=kc, v=vc)
+        states = {n: cache[n] for n in ("ssm", "conv_x", "conv_b", "conv_c")}
+        x, states, (kc, vc) = _hybrid_stack(
+            cfg, params, x, states, (cache["k"], cache["v"]), cos, sin, pos,
+            dtype)
+        new_cache.update(states, k=kc, v=vc)
     elif fam == "moe" and cfg.mla is not None:
         fk = cfg.moe.first_k_dense
 

@@ -29,43 +29,52 @@ def mamba_defs(cfg, ll=()) -> dict:
     d = cfg.d_model
     di = s.d_inner(d)
     nh = s.n_heads(d)
-    ns = s.d_state
+    gns = s.ngroups * s.d_state
     Lax = tuple("layers" for _ in ll)
     # zamba2 (nh=80) shards SSM heads over `model`; mamba2-130m (nh=24)
     # does not divide the 16-wide axis -> replicated (see DESIGN.md).
     hax = "ssm_heads" if nh % 16 == 0 else "ssm_heads_rep"
-    return {
+    out = {
         "wz": ParamDef(ll + (d, di), Lax + ("embed", hax)),
         "wx": ParamDef(ll + (d, di), Lax + ("embed", hax)),
-        "wb": ParamDef(ll + (d, ns), Lax + ("embed", "ssm_state")),
-        "wc": ParamDef(ll + (d, ns), Lax + ("embed", "ssm_state")),
+        "wb": ParamDef(ll + (d, gns), Lax + ("embed", "ssm_state")),
+        "wc": ParamDef(ll + (d, gns), Lax + ("embed", "ssm_state")),
         "wdt": ParamDef(ll + (d, nh), Lax + ("embed", hax)),
         "dt_bias": ParamDef(ll + (nh,), Lax + (hax,), init="zeros"),
         "A_log": ParamDef(ll + (nh,), Lax + (hax,), init="ones"),
         "D": ParamDef(ll + (nh,), Lax + (hax,), init="ones"),
         "conv_x": ParamDef(ll + (s.d_conv, di), Lax + ("conv", hax),
                            scale=0.5),
-        "conv_b": ParamDef(ll + (s.d_conv, ns), Lax + ("conv", "ssm_state"),
+        "conv_b": ParamDef(ll + (s.d_conv, gns), Lax + ("conv", "ssm_state"),
                            scale=0.5),
-        "conv_c": ParamDef(ll + (s.d_conv, ns), Lax + ("conv", "ssm_state"),
+        "conv_c": ParamDef(ll + (s.d_conv, gns), Lax + ("conv", "ssm_state"),
                            scale=0.5),
         "norm": ParamDef(ll + (di,), Lax + (hax,), init="ones"),
         "wo": ParamDef(ll + (di, d), Lax + (hax, "embed")),
     }
+    if s.conv_bias:
+        out["conv_x_bias"] = ParamDef(ll + (di,), Lax + (hax,), init="zeros")
+        for n in ("conv_b_bias", "conv_c_bias"):
+            out[n] = ParamDef(ll + (gns,), Lax + ("ssm_state",), init="zeros")
+    if s.pre_norm:
+        out["in_norm"] = ParamDef(ll + (d,), Lax + ("embed",), init="ones")
+    return out
 
 
 # ---------------------------------------------------------------------------
 # Causal depthwise conv (d_conv taps) as shifted adds — no conv primitive
 # ---------------------------------------------------------------------------
 
-def causal_conv(u, w, state=None):
-    """u: (B, S, C); w: (taps, C). state: (B, taps-1, C) history or None.
-    Returns (y, new_state)."""
+def causal_conv(u, w, state=None, bias=None):
+    """u: (B, S, C); w: (taps, C); bias: (C,) or None. state: (B, taps-1, C)
+    history or None. Returns (y, new_state)."""
     taps = w.shape[0]
     if state is None:
         state = jnp.zeros((u.shape[0], taps - 1, u.shape[2]), u.dtype)
     ext = jnp.concatenate([state, u], axis=1)              # (B, S+taps-1, C)
     y = sum(ext[:, i:i + u.shape[1]] * w[i] for i in range(taps))
+    if bias is not None:
+        y = y + bias
     return y, ext[:, -(taps - 1):]
 
 
@@ -76,8 +85,12 @@ def causal_conv(u, w, state=None):
 def ssd_chunked(x, dt, A_log, B_, C_, D_, chunk: int, state=None,
                 return_state: bool = False, einsum_dtype=jnp.float32):
     """x: (B,S,nh,hp); dt: (B,S,nh) (post-softplus); A_log: (nh,);
-    B_/C_: (B,S,ns) (single group shared by all heads); D_: (nh,).
+    B_/C_: (B,S,ns), one group shared by all heads, or (B,S,G,ns), where
+    head h reads group h // (nh/G); D_: (nh,).
     state: (B,nh,hp,ns) initial inter-chunk state."""
+    if B_.ndim == 4:
+        return _ssd_grouped(x, dt, A_log, B_, C_, D_, chunk, state,
+                            return_state, einsum_dtype)
     B, S, nh, hp = x.shape
     ns = B_.shape[-1]
     cl = min(chunk, S)
@@ -148,6 +161,29 @@ def ssd_chunked(x, dt, A_log, B_, C_, D_, chunk: int, state=None,
     return (y, state) if return_state else y
 
 
+def _ssd_grouped(x, dt, A_log, B_, C_, D_, chunk, state, return_state,
+                 einsum_dtype):
+    """Grouped B/C: the heads of one group share its B and C and no head
+    reads another group, so the SSD is the single-group SSD of each group."""
+    B, S, nh, hp = x.shape
+    G = B_.shape[2]
+
+    def heads(t, ax):                       # split the head axis into groups
+        return t.reshape(t.shape[:ax] + (G, nh // G) + t.shape[ax + 1:])
+
+    def one(x, dt, A_log, B_, C_, D_, st):
+        return ssd_chunked(x, dt, A_log, B_, C_, D_, chunk, state=st,
+                           return_state=True, einsum_dtype=einsum_dtype)
+
+    if state is None:
+        state = jnp.zeros((B, nh, hp, B_.shape[-1]), jnp.float32)
+    y, st = jax.vmap(one, in_axes=(2, 2, 0, 2, 2, 0, 1), out_axes=(2, 1))(
+        heads(x, 2), heads(dt, 2), heads(A_log, 0), B_, C_, heads(D_, 0),
+        heads(state, 1))
+    y, st = y.reshape(x.shape), st.reshape(state.shape)
+    return (y, st) if return_state else y
+
+
 def ssd_decode_step(x, dt, A_log, B_, C_, D_, state):
     """Single-token recurrence. x: (B,nh,hp); dt: (B,nh); B_/C_: (B,ns);
     state: (B,nh,hp,ns) → (y, new_state)."""
@@ -177,6 +213,9 @@ def mamba_block(cfg, p, u, dtype, *, state=None, conv_state=None,
     di = s.d_inner(D)
     nh = s.n_heads(D)
     ns = s.d_state
+    G = s.ngroups
+    if s.pre_norm:
+        u = rms_norm(u, p["in_norm"], cfg.norm_eps)
 
     z = jnp.einsum("bsd,de->bse", u, p["wz"].astype(dtype))
     xs = jnp.einsum("bsd,de->bse", u, p["wx"].astype(dtype))
@@ -185,13 +224,17 @@ def mamba_block(cfg, p, u, dtype, *, state=None, conv_state=None,
     dt = jax.nn.softplus(
         jnp.einsum("bsd,dh->bsh", u, p["wdt"].astype(dtype))
         .astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    if s.dt_min:
+        dt = jnp.maximum(dt, s.dt_min)
 
     cx = cb = cc = None
     if conv_state is not None:
         cx, cb, cc = conv_state
-    xs, cx = causal_conv(xs, p["conv_x"].astype(dtype), cx)
-    bs, cb = causal_conv(bs, p["conv_b"].astype(dtype), cb)
-    cs2, cc = causal_conv(cs, p["conv_c"].astype(dtype), cc)
+    bias = {n: p[n + "_bias"].astype(dtype) if s.conv_bias else None
+            for n in ("conv_x", "conv_b", "conv_c")}
+    xs, cx = causal_conv(xs, p["conv_x"].astype(dtype), cx, bias["conv_x"])
+    bs, cb = causal_conv(bs, p["conv_b"].astype(dtype), cb, bias["conv_b"])
+    cs2, cc = causal_conv(cs, p["conv_c"].astype(dtype), cc, bias["conv_c"])
     xs = jax.nn.silu(xs)
     bs = jax.nn.silu(bs)
     cs2 = jax.nn.silu(cs2)
@@ -205,6 +248,8 @@ def mamba_block(cfg, p, u, dtype, *, state=None, conv_state=None,
         bs = constrain(bs, mesh, "batch", None, None, rules=rules)
         cs2 = constrain(cs2, mesh, "batch", None, None, rules=rules)
     xh = xs.reshape(B, S, nh, s.headdim)
+    if G > 1:                  # (B, S, G, ns): head h reads group h // (nh/G)
+        bs, cs2 = bs.reshape(B, S, G, ns), cs2.reshape(B, S, G, ns)
     chunk = cfg.ssm_chunk or s.chunk
     if use_pallas:
         from repro.kernels.ssd_scan import ops as ssd_ops
@@ -216,8 +261,14 @@ def mamba_block(cfg, p, u, dtype, *, state=None, conv_state=None,
             return_state=True,
             einsum_dtype=jnp.bfloat16 if cfg.ssm_bf16 else jnp.float32)
     y = y.reshape(B, S, di)
-    # gated RMSNorm (Mamba2): norm(y * silu(z))
-    y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    # gated RMSNorm (Mamba2): norm(y * silu(z)), over each group's channels
+    y = y * jax.nn.silu(z)
+    if G > 1:
+        y = rms_norm(y.reshape(B, S, G, di // G),
+                     p["norm"].reshape(G, di // G),
+                     cfg.norm_eps).reshape(B, S, di)
+    else:
+        y = rms_norm(y, p["norm"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, p["wo"].astype(dtype))
     if return_state:
         return out, new_state, (cx, cb, cc)

@@ -21,7 +21,8 @@ from repro.models import lm
 GENERATE = "serve.generate"   # the call: requests, prompt_len, new_tokens
 UPLOAD = "serve.upload"       # the prompts to the device
 PREFILL = "serve.prefill"     # the prefill dispatch
-CACHE = "serve.cache"         # the max_len cache made and filled
+CACHE = "serve.cache"         # the max_len cache made and filled: kv_bytes,
+                              # state_bytes
 STEP = "serve.step"           # one decode step's dispatch: pos
 CONCAT = "serve.concat"       # the tokens joined
 
@@ -48,7 +49,8 @@ class ServeLoop:
                 batch = {"tokens": jnp.asarray(prompt_tokens, jnp.int32)}
             with TraceAnnotation(PREFILL, batch=b):
                 logits, cache = self.prefill(self.params, batch)
-            with TraceAnnotation(CACHE, batch=b):
+            with TraceAnnotation(CACHE, batch=b,
+                                 **lm.cache_bytes(cfg, self.max_len, B)):
                 cache = self._full_cache(cache, B)
 
             nxt = jnp.argmax(logits[..., :cfg.vocab_size],

@@ -155,3 +155,70 @@ def test_stablelm_serve_step_never_copies_the_kv_cache(stablelm_serve_step):
             moved.append(line.strip()[:120])
     assert not moved, moved
     assert c.memory_analysis().temp_size_in_bytes < 3.92 * 2**30
+
+
+@pytest.fixture(scope="module")
+def zamba2_cell(one_chip):
+    """zamba2-7b as its benchmark cell serves it (the configuration file:
+    24 layers, bfloat16 weights; B=8, 3072-token prompts, max_len 3328):
+    its serve step with the cache donated and its prefill step, compiled."""
+    from chipbench import program
+    from chipbench.spec import HERE, load_json
+    from repro.launch.steps import make_prefill_step
+    cfg = program.model_config(load_json(HERE / "configs" / "zamba2-7b.json"))
+    B, S0, max_len = 8, 3072, 3328
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,   # noqa: E731
+                                           sharding=one_chip)
+    params = jax.tree_util.tree_map(place, lm.abstract_params(cfg))
+    cache = jax.tree_util.tree_map(place, lm.abstract_cache(cfg, max_len, B))
+    arg = lambda s: jax.ShapeDtypeStruct(s, jnp.int32,         # noqa: E731
+                                         sharding=one_chip)
+    serve = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, arg((B, 1)), arg(())).compile()
+    prefill = jax.jit(make_prefill_step(cfg)).lower(
+        params, {"tokens": arg((B, S0))}).compile()
+    return serve, prefill, cache["k"].shape
+
+
+def test_zamba2_steps_fit_one_chip(zamba2_cell):
+    """Arguments, temporaries and outputs of each step fit one chip's HBM
+    with room left (described v5e: serve step 8.32 + 1.80 GiB, prefill
+    5.09 + 5.08 + 3.01 GiB)."""
+    serve, prefill, _ = zamba2_cell
+    for c in (serve, prefill):
+        m = c.memory_analysis()
+        need = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+        assert need < 15 * 2**30, (m.argument_size_in_bytes,
+                                   m.temp_size_in_bytes,
+                                   m.output_size_in_bytes)
+
+
+def test_zamba2_serve_step_never_copies_the_kv_cache(zamba2_cell):
+    """Each site writes its token into its K/V rows in place: no copy or
+    transpose moves the sites' stack or one site of it."""
+    serve, _, shape = zamba2_cell
+    stack = math.prod(shape)
+    sizes = {stack, stack // shape[0]}
+    moved = []
+    for line in serve.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
+                in sizes:
+            moved.append(line.strip()[:120])
+    assert not moved, moved
+    assert "tpu_custom_call" not in serve.as_text()   # decode: no SSD kernel
+
+
+def test_zamba2_prefill_keeps_the_kernel_name_its_roofline_reads(
+        zamba2_cell):
+    """The grouped SSD kernel in zamba2-7b's prefill is the Pallas call
+    that ``ssd_scan_roofline.gen`` counts (the reader of
+    ``ssd_scan_roofline``)."""
+    from chipbench import tracing
+    from chipbench.spec import load_module
+    _, prefill, _ = zamba2_cell
+    names = {tracing.kernel_name(line.strip())
+             for line in prefill.as_text().splitlines()}
+    assert load_module("metrics", "ssd_scan_roofline").KERNEL in names

@@ -73,6 +73,41 @@ def test_ssd_kernel_with_initial_state():
                                rtol=2e-4)
 
 
+@pytest.mark.parametrize("with_state", [False, True])
+@pytest.mark.parametrize("B,S,nh,hp,ns,cl,G", [
+    (2, 128, 4, 32, 16, 32, 2),
+    (1, 96, 8, 16, 32, 64, 4),        # S not a multiple of the chunk
+])
+def test_grouped_ssd_kernel_sweep(B, S, nh, hp, ns, cl, G, with_state):
+    """B and C in G groups: the kernel's per-group C·Bᵀ and the grouped
+    inter-chunk scan against the chunked jnp SSD, itself the single-group
+    SSD of each group's heads."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    x = jax.random.normal(ks[0], (B, S, nh, hp), jnp.float32) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, nh)))
+    A_log = jax.random.normal(ks[2], (nh,)) * 0.3
+    B_ = jax.random.normal(ks[3], (B, S, G, ns)) * 0.5
+    C_ = jax.random.normal(ks[4], (B, S, G, ns)) * 0.5
+    D_ = jnp.linspace(0.5, 1.5, nh)
+    st0 = (jax.random.normal(ks[5], (B, nh, hp, ns)) * 0.2 if with_state
+           else None)
+    y, st = pk_ssd(x, dt, A_log, B_, C_, D_, chunk=cl, state=st0,
+                   interpret=True)
+    yr, sr = ssd_ref(x, dt, A_log, B_, C_, D_, cl, state=st0)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=2e-5,
+                               rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(st), np.asarray(sr), atol=2e-5,
+                               rtol=2e-4)
+    # head h reads group h // (nh/G): group g alone gives its heads' part
+    g, per = 1, nh // G
+    heads = slice(g * per, (g + 1) * per)
+    y1, _ = ssd_ref(x[:, :, heads], dt[..., heads], A_log[heads],
+                    B_[:, :, g], C_[:, :, g], D_[heads], cl,
+                    state=None if st0 is None else st0[:, heads])
+    np.testing.assert_allclose(np.asarray(yr[:, :, heads]), np.asarray(y1),
+                               atol=1e-6, rtol=1e-6)
+
+
 @pytest.mark.parametrize("B,H,KH,hd,page,nblk", [
     (2, 4, 2, 64, 32, 4),
     (3, 8, 2, 64, 16, 8),

@@ -200,7 +200,7 @@ def test_moe_capacity_drops_are_bounded():
 def test_cell_enumeration():
     from repro.configs import cells
     all_cells = cells(include_skipped=True)
-    assert len(all_cells) == 40
+    assert len(all_cells) == 44
     skipped = [c for c in all_cells if not c[2]]
     assert len(skipped) == 7          # pure full-attention archs x long_500k
     assert all(c[1] == "long_500k" for c in skipped)

@@ -18,6 +18,10 @@ heads are a grid axis (never a lane slice), x arrives transposed to
 row vectors. C·Bᵀ is shared by the heads of a group (all heads of a
 chunk when B and C have one group): it is computed at the group's first
 head into VMEM scratch, which is why the head axis is sequential.
+
+``ssd_state_call`` is decode's one-token step of the same recurrence,
+h = h·exp(dt·A) + (x·dt)⊗B and y = h·C, on one layer of the stacked
+decode state, updated where it lies (see its docstring).
 """
 
 from __future__ import annotations
@@ -142,3 +146,96 @@ def ssd_chunk_call(x, dt, A_log, B_, C_, *, chunk: int,
             st.reshape(B, nc, nh, hp, ns),
             ecs,
             ecs[:, :, -1])                 # exp(total) = exp(cs[last])
+
+
+# bytes of one (batch block, head block) tile of the state: four such tiles
+# (read and written, each double-buffered) and the loop's temporaries stay
+# well inside the VMEM limit below
+TILE_BYTES = 4 * 2**20
+VMEM_LIMIT = 48 * 2**20
+
+
+def _ssd_state_kernel(layer_ref, st_ref, xdt_ref, dA_ref, B_ref, C_ref,
+                      st_out_ref, y_ref, *, ns: int):
+    del layer_ref                             # read by the index maps
+    k, bB, _, R = xdt_ref.shape
+    # lane l of a stored row holds position p = k·row + l // ns
+    part = jax.lax.broadcasted_iota(jnp.int32, (R, st_ref.shape[-1]), 1) // ns
+
+    def one(b, carry):                        # one sequence of the block
+        x = xdt_ref[0, b][..., None]          # (bH, R, 1)
+        for j in range(1, k):
+            x = jnp.where(part == j, xdt_ref[j, b][..., None], x)
+        h = st_ref[0, b] * dA_ref[b][..., None] + x * B_ref[0, b]
+        st_out_ref[0, b] = h
+        hc = h * C_ref[0, b]
+        for j in range(k):
+            y_ref[j, b] = jnp.sum(
+                hc if k == 1 else jnp.where(part == j, hc, 0.0), axis=-1)
+        return carry
+
+    jax.lax.fori_loop(0, bB, one, 0)
+
+
+def ssd_state_call(state, layer, xdt, dA, B_, C_, *,
+                   interpret: bool = False):
+    """One token of the SSD recurrence on layer ``layer`` of the stacked
+    decode state, in place.
+
+    state: (L, B, nh, R, W) float32, each head's (hp, ns) state stored as
+    (hp/k, k·ns) (``models.mamba.state_layout``); layer: int32 scalar;
+    xdt: (B, nh, hp) x·dt; dA: (B, nh) exp(dt·A); B_/C_: (B, G, ns), head
+    h reading group h // (nh/G).
+
+    The state is aliased to output 0: each grid step reads one (batch
+    block, head block) tile of the layer once, writes h·dA + xdt⊗B back
+    where it was, and emits y = h·C. A head block lies inside one group
+    and holds a multiple of 8 heads, or every head: where nh/G has no such
+    divisor (G > 1 and nh/G not a multiple of 8) the call is refused.
+    Returns (y (B, nh, hp) float32, state)."""
+    L, B, nh, R, W = state.shape
+    G, ns = B_.shape[1], B_.shape[2]
+    hp = xdt.shape[-1]
+    k, hpg = W // ns, nh // G
+    assert k * ns == W and k * R == hp and hpg * G == nh
+    head = R * W * 4                                     # bytes a head
+    # a head block lies in one group and, unless it holds every head,
+    # fills whole sublane tiles of xdt, dA and y
+    small = [d for d in range(1, hpg + 1)
+             if hpg % d == 0 and (d % 8 == 0 or d == nh)
+             and d * head <= TILE_BYTES]
+    assert small, (f"no head block of {nh} heads in {G} groups is a "
+                   f"multiple of 8 with a tile of at most {TILE_BYTES} "
+                   f"bytes ({head} bytes a head)")
+    bH = max(small)
+    bB = max(d for d in range(1, B + 1)
+             if B % d == 0 and d * bH * head <= TILE_BYTES)
+
+    xk = jnp.moveaxis(xdt.reshape(B, nh, R, k), -1, 0)   # (k, B, nh, R)
+    bc = lambda t: jnp.swapaxes(jnp.tile(t.astype(jnp.float32),  # noqa
+                                         (1, 1, k)), 0, 1)[:, :, None]
+    st_spec = pl.BlockSpec((1, bB, bH, R, W),
+                           lambda b, h, l: (l[0], b, h, 0, 0))
+    bc_spec = pl.BlockSpec((1, bB, 1, W),
+                           lambda b, h, l: (h * bH // hpg, b, 0, 0))
+    x_spec = pl.BlockSpec((k, bB, bH, R), lambda b, h, l: (0, b, h, 0))
+    state, y = pl.pallas_call(
+        functools.partial(_ssd_state_kernel, ns=ns),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B // bB, nh // bH),
+            in_specs=[st_spec, x_spec,
+                      pl.BlockSpec((bB, bH, 1), lambda b, h, l: (b, h, 0)),
+                      bc_spec, bc_spec],
+            out_specs=[st_spec, x_spec]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((k, B, nh, R), jnp.float32)],
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        name="ssd_state",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), state,
+      xk.astype(jnp.float32), dA.astype(jnp.float32)[..., None],
+      bc(B_), bc(C_))
+    return jnp.moveaxis(y, 0, -1).reshape(B, nh, hp), state

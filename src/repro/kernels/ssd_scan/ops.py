@@ -1,5 +1,8 @@
-"""jit'd SSD wrapper: Pallas intra-chunk kernel + jnp inter-chunk scan.
-The kernel compiles unless the caller passes ``interpret=True``."""
+"""jit'd SSD wrappers: ``ssd`` (prefill: Pallas intra-chunk kernel + jnp
+inter-chunk scan; it compiles unless the caller passes ``interpret=True``)
+and ``ssd_state`` (decode: one token on the stacked state, in place).
+Each wrapper's name is its Pallas call's name in the compiled program and
+the trace."""
 
 from __future__ import annotations
 
@@ -8,7 +11,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ssd_scan.kernel import ssd_chunk_call
+from repro.kernels.ssd_scan.kernel import ssd_chunk_call, ssd_state_call
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -65,3 +68,13 @@ def ssd(x, dt, A_log, B_, C_, D_, *, chunk: int = 256, state=None,
     y = y + x.astype(jnp.float32) * D_.astype(jnp.float32)[None, None, :,
                                                            None]
     return y.astype(x.dtype)[:, :S_orig], state
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def ssd_state(state, layer, xdt, dA, B_, C_, *, interpret: bool = False):
+    """Decode's SSD step on layer ``layer`` of the stacked state (L, B, nh,
+    R, W), updated in place (``kernel.ssd_state_call``); it compiles unless
+    the caller passes ``interpret=True``.
+    Returns (y (B, nh, hp) float32 without the D skip, state)."""
+    return ssd_state_call(state, layer, xdt, dA, B_, C_,
+                          interpret=interpret)

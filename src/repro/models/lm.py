@@ -346,13 +346,14 @@ def _hybrid_stack(cfg, params, x, states, kv, cos, sin, pos, dtype,
     [x, embedding] and the site's linear gives t; that layer then computes
     x + Mamba(norm(x + t)), and every other layer x + Mamba(norm(x)).
 
-    ``states`` holds every layer's SSM and conv state (row i: layer i),
-    rewritten row by row in place. In prefill (``pos`` None) the layers
-    start from zero state and each site appends its (k, v) to ``kv``; in
-    decode they continue from ``states`` and the sites attend over their
-    rows of the caches ``kv`` = (k, v), writing the token at ``pos``. Runs
-    of Mamba layers between sites are loops over the stacked layers. One
-    jitted call, so that eager callers compile the stack once.
+    ``states`` holds every layer's SSM and conv state (row i: layer i; the
+    SSM state in the decode cache's layout), rewritten row by row in
+    place. In prefill (``pos`` None) the layers start from zero state and
+    each site appends its (k, v) to ``kv``; in decode they continue from
+    ``states`` and the sites attend over their rows of the caches ``kv`` =
+    (k, v), writing the token at ``pos``. Runs of Mamba layers between
+    sites are loops over the stacked layers. One jitted call, so that
+    eager callers compile the stack once.
     Returns (x, states, kv)."""
     e = x
     sites = cfg.hybrid_sites
@@ -363,17 +364,23 @@ def _hybrid_stack(cfg, params, x, states, kv, cos, sin, pos, dtype,
         at = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,      # noqa
                                                     keepdims=False)
         p = jax.tree_util.tree_map(at, params["layers"])
-        st = conv = None
-        if decode:
-            st, conv = at(states["ssm"]), tuple(at(states[n])
-                                                for n in names[1:])
         u = x if t is None else x + t
-        y, st, conv = mam.mamba_block(cfg, p, u, dtype, state=st,
-                                      conv_state=conv, return_state=True,
-                                      use_pallas=use_pallas)
-        new = dict(zip(names, (st,) + conv))
+        if decode:
+            y, ssm, conv = mam.mamba_decode(
+                cfg, p, u, states["ssm"], i,
+                tuple(at(states[n]) for n in names[1:]), dtype,
+                use_pallas=use_pallas)
+            states = dict(states, ssm=ssm)
+            new = dict(zip(names[1:], conv))
+        else:
+            y, st, conv = mam.mamba_block(cfg, p, u, dtype,
+                                          return_state=True,
+                                          use_pallas=use_pallas)
+            new = dict(zip(names, (st.reshape(states["ssm"].shape[1:]),)
+                           + conv))
         return x + y, {n: jax.lax.dynamic_update_index_in_dim(
-            a, new[n].astype(a.dtype), i, 0) for n, a in states.items()}
+            a, new[n].astype(a.dtype), i, 0) if n in new else a
+            for n, a in states.items()}
 
     def site_attn(blk, h, k, kv):
         if not decode:
@@ -520,7 +527,9 @@ def prefill_cache(cfg, caches, S: int) -> dict:
 
     fam = cfg.family
     if fam in ("ssm", "hybrid"):
-        out["ssm"] = caches["ssm"].astype(jnp.float32)
+        st = caches["ssm"]
+        out["ssm"] = st.reshape(st.shape[:3] + mam.state_layout(
+            cfg.ssm.headdim, cfg.ssm.d_state)).astype(jnp.float32)
         for n in ("conv_x", "conv_b", "conv_c"):
             out[n] = caches[n].astype(jnp.bfloat16)
     if fam == "hybrid":
@@ -549,7 +558,9 @@ def cache_spec_defs(cfg, max_len: int, batch: int) -> dict:
 
     A K or V cache is (layers, B, KH·hd, S): the sequence is the minor
     axis, which is how the prefill's attention leaves it and how decode's
-    score and value contractions read it, so neither relays it out."""
+    score and value contractions read it, so neither relays it out. The
+    SSM state stores each head's (hp, ns) as ``mamba.state_layout`` says,
+    rows as wide as the decode kernel reads them."""
     dt = "bfloat16"
     L = cfg.n_layers
     fam = cfg.family
@@ -573,7 +584,8 @@ def cache_spec_defs(cfg, max_len: int, batch: int) -> dict:
         di, nh, ns = s.d_inner(cfg.d_model), s.n_heads(cfg.d_model), s.d_state
         gns = s.ngroups * ns
         hax = "ssm_heads" if nh % 16 == 0 else "ssm_heads_rep"
-        defs["ssm"] = ParamDef((L, batch, nh, s.headdim, ns),
+        defs["ssm"] = ParamDef((L, batch, nh)
+                               + mam.state_layout(s.headdim, ns),
                                ("layers", "batch", hax, None, "ssm_state"),
                                dtype="float32")
         defs["conv_x"] = ParamDef((L, batch, s.d_conv - 1, di),
@@ -705,19 +717,21 @@ def decode_step(cfg, params, cache, tokens, pos):
     new_cache = dict(cache)
     if fam == "ssm":
         def body(carry, xs):
-            p_l, st, cx, cb, cc = xs
-            y, st2, conv2 = mam.mamba_decode_block(cfg, p_l, carry, st,
-                                                   (cx, cb, cc), dtype)
-            return carry + y, (st2,) + conv2
-        x, (st, cx, cb, cc) = jax.lax.scan(
-            body, x, (params["layers"], cache["ssm"], cache["conv_x"],
-                      cache["conv_b"], cache["conv_c"]))
+            (xc, ssm), (p_l, layer, cx, cb, cc) = carry, xs
+            y, ssm, conv = mam.mamba_decode(cfg, p_l, xc, ssm, layer,
+                                            (cx, cb, cc), dtype,
+                                            use_pallas=cfg.use_pallas)
+            return (xc + y, ssm), conv
+        (x, st), (cx, cb, cc) = jax.lax.scan(
+            body, (x, cache["ssm"]),
+            (params["layers"], jnp.arange(cfg.n_layers), cache["conv_x"],
+             cache["conv_b"], cache["conv_c"]))
         new_cache.update(ssm=st, conv_x=cx, conv_b=cb, conv_c=cc)
     elif fam == "hybrid":
         states = {n: cache[n] for n in ("ssm", "conv_x", "conv_b", "conv_c")}
         x, states, (kc, vc) = _hybrid_stack(
             cfg, params, x, states, (cache["k"], cache["v"]), cos, sin, pos,
-            dtype)
+            dtype, use_pallas=cfg.use_pallas)
         new_cache.update(states, k=kc, v=vc)
     elif fam == "moe" and cfg.mla is not None:
         fk = cfg.moe.first_k_dense

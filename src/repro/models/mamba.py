@@ -6,11 +6,14 @@ the scan step so live memory stays O(B·chunk²·heads) instead of
 O(B·S·chunk·heads); the inter-chunk state is the scan carry — exactly the
 "recurrent outer, attention inner" duality of the paper [arXiv:2405.21060].
 
-``kernels/ssd_scan`` provides the Pallas TPU kernel for the intra-chunk
-part; this module is its jnp oracle and the default (CPU/dry-run) path.
+``kernels/ssd_scan`` provides the Pallas TPU kernels for the intra-chunk
+part and for decode's one-token state update; this module is their jnp
+oracle and the default (CPU/dry-run) path.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -185,32 +188,45 @@ def _ssd_grouped(x, dt, A_log, B_, C_, D_, chunk, state, return_state,
 
 
 def ssd_decode_step(x, dt, A_log, B_, C_, D_, state):
-    """Single-token recurrence. x: (B,nh,hp); dt: (B,nh); B_/C_: (B,ns);
-    state: (B,nh,hp,ns) → (y, new_state)."""
+    """Single-token recurrence. x: (B,nh,hp); dt: (B,nh); B_/C_: (B,ns), or
+    (B,G,ns) where head h reads group h // (nh/G); state: (B,nh,hp,ns) →
+    (y, new_state). The state is read once: y = C·(state·dA + xdt⊗B)."""
+    if B_.ndim == 2:
+        B_, C_ = B_[:, None], C_[:, None]
+    per = x.shape[1] // B_.shape[1]                    # heads of a group
+    Bh, Ch = (jnp.repeat(t.astype(jnp.float32), per, axis=1)
+              for t in (B_, C_))                       # (B,nh,ns)
     A = -jnp.exp(A_log.astype(jnp.float32))
     dtf = dt.astype(jnp.float32)
-    dA = jnp.exp(dtf * A)                                  # (B,nh)
+    dA = jnp.exp(dtf * A)                              # (B,nh)
     xf = x.astype(jnp.float32)
-    upd = jnp.einsum("bhp,bn->bhpn", xf * dtf[..., None],
-                     B_.astype(jnp.float32))
+    upd = (xf * dtf[..., None])[..., None] * Bh[:, :, None, :]
     state = state * dA[..., None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", state, C_.astype(jnp.float32))
+    y = jnp.sum(state * Ch[:, :, None, :], axis=-1)
     y = y + xf * D_.astype(jnp.float32)[None, :, None]
     return y.astype(x.dtype), state
+
+
+def state_layout(hp: int, ns: int) -> tuple:
+    """How the decode cache stores one head's (hp, ns) float32 state: as
+    (hp/k, k·ns), k positions side by side in a row, so that a row fills
+    the 128 lanes of a TPU vector register where ns < 128 (zamba2's 64: k
+    = 2) and the decode kernel reads it without a relayout. A row-major
+    reshape: element (p, n) lies at (p // k, (p % k)·ns + n)."""
+    k = math.gcd(hp, max(1, 128 // ns))
+    return hp // k, k * ns
 
 
 # ---------------------------------------------------------------------------
 # Full Mamba2 block
 # ---------------------------------------------------------------------------
 
-def mamba_block(cfg, p, u, dtype, *, state=None, conv_state=None,
-                return_state: bool = False, use_pallas: bool = False,
-                mesh=None, rules=None):
-    """u: (B, S, D). ``state``/``conv_state`` enable decode-style chunked
-    streaming; None for training."""
+def _mixer_in(cfg, p, u, dtype, conv_state=None, mesh=None, rules=None):
+    """u: (B, S, D) → z (B,S,di), x (B,S,nh,hp), dt (B,S,nh) float32 after
+    softplus, B/C (B,S,ns), or (B,S,G,ns) where head h reads group
+    h // (nh/G), and the convolution's new state."""
     s = cfg.ssm
     B, S, D = u.shape
-    di = s.d_inner(D)
     nh = s.n_heads(D)
     ns = s.d_state
     G = s.ngroups
@@ -250,34 +266,70 @@ def mamba_block(cfg, p, u, dtype, *, state=None, conv_state=None,
     xh = xs.reshape(B, S, nh, s.headdim)
     if G > 1:                  # (B, S, G, ns): head h reads group h // (nh/G)
         bs, cs2 = bs.reshape(B, S, G, ns), cs2.reshape(B, S, G, ns)
-    chunk = cfg.ssm_chunk or s.chunk
-    if use_pallas:
-        from repro.kernels.ssd_scan import ops as ssd_ops
-        y, new_state = ssd_ops.ssd(xh, dt, p["A_log"], bs, cs2, p["D"],
-                                   chunk=chunk, state=state)
-    else:
-        y, new_state = ssd_chunked(
-            xh, dt, p["A_log"], bs, cs2, p["D"], chunk, state=state,
-            return_state=True,
-            einsum_dtype=jnp.bfloat16 if cfg.ssm_bf16 else jnp.float32)
-    y = y.reshape(B, S, di)
-    # gated RMSNorm (Mamba2): norm(y * silu(z)), over each group's channels
-    y = y * jax.nn.silu(z)
+    return z, xh, dt, bs, cs2, (cx, cb, cc)
+
+
+def _mixer_out(cfg, p, y, z, dtype):
+    """y (B,S,nh,hp) → the gated RMSNorm (Mamba2): norm(y * silu(z)), over
+    each group's channels, then the out-projection (B,S,D)."""
+    B, S = y.shape[:2]
+    di = z.shape[-1]
+    G = cfg.ssm.ngroups
+    y = y.reshape(B, S, di) * jax.nn.silu(z)
     if G > 1:
         y = rms_norm(y.reshape(B, S, G, di // G),
                      p["norm"].reshape(G, di // G),
                      cfg.norm_eps).reshape(B, S, di)
     else:
         y = rms_norm(y, p["norm"], cfg.norm_eps)
-    out = jnp.einsum("bse,ed->bsd", y, p["wo"].astype(dtype))
+    return jnp.einsum("bse,ed->bsd", y, p["wo"].astype(dtype))
+
+
+def mamba_block(cfg, p, u, dtype, *, return_state: bool = False,
+                use_pallas: bool = False, mesh=None, rules=None):
+    """u: (B, S, D) from zero state (training, prefill). With
+    ``return_state`` also the final SSM state (B,nh,hp,ns) and the
+    convolution's last inputs, which ``mamba_decode`` continues from."""
+    z, xh, dt, bs, cs2, conv = _mixer_in(cfg, p, u, dtype, mesh=mesh,
+                                         rules=rules)
+    chunk = cfg.ssm_chunk or cfg.ssm.chunk
+    if use_pallas:
+        from repro.kernels.ssd_scan import ops as ssd_ops
+        y, new_state = ssd_ops.ssd(xh, dt, p["A_log"], bs, cs2, p["D"],
+                                   chunk=chunk)
+    else:
+        y, new_state = ssd_chunked(
+            xh, dt, p["A_log"], bs, cs2, p["D"], chunk, return_state=True,
+            einsum_dtype=jnp.bfloat16 if cfg.ssm_bf16 else jnp.float32)
+    out = _mixer_out(cfg, p, y, z, dtype)
     if return_state:
-        return out, new_state, (cx, cb, cc)
+        return out, new_state, conv
     return out
 
 
-def mamba_decode_block(cfg, p, u, state, conv_state, dtype):
-    """u: (B, 1, D) single step."""
-    out, new_state, new_conv = mamba_block(
-        cfg, p, u, dtype, state=state, conv_state=conv_state,
-        return_state=True)
-    return out, new_state, new_conv
+def mamba_decode(cfg, p, u, ssm, layer, conv_state, dtype, *,
+                 use_pallas: bool = False):
+    """One token, u: (B, 1, D). ``ssm`` is every layer's state as the
+    decode cache stores it, (L, B, nh) + ``state_layout(hp, ns)`` float32,
+    of which row ``layer`` is this block's and is updated in place: by the
+    Pallas ``ssd_state`` kernel with ``use_pallas``, else by
+    ``ssd_decode_step``. conv_state: this layer's (cx, cb, cc).
+    Returns (out (B,1,D), ssm, conv_state)."""
+    z, xh, dt, bs, cs, conv = _mixer_in(cfg, p, u, dtype, conv_state)
+    x, dt, b, c = xh[:, 0], dt[:, 0], bs[:, 0], cs[:, 0]
+    if b.ndim == 2:                                    # one group
+        b, c = b[:, None], c[:, None]
+    if use_pallas:
+        from repro.kernels.ssd_scan import ops as ssd_ops
+        xf = x.astype(jnp.float32)
+        dA = jnp.exp(dt * -jnp.exp(p["A_log"].astype(jnp.float32)))
+        y, ssm = ssd_ops.ssd_state(ssm, layer, xf * dt[..., None], dA, b, c)
+        y = (y + xf * p["D"].astype(jnp.float32)[None, :, None]
+             ).astype(x.dtype)
+    else:
+        row = jax.lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False)
+        y, st = ssd_decode_step(x, dt, p["A_log"], b, c, p["D"],
+                                row.reshape(x.shape + (b.shape[-1],)))
+        ssm = jax.lax.dynamic_update_index_in_dim(
+            ssm, st.reshape(row.shape), layer, 0)
+    return _mixer_out(cfg, p, y[:, None], z, dtype), ssm, conv

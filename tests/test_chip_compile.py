@@ -55,6 +55,50 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernels(compiled) -> set:
+    """Names of the Pallas calls in a compiled program, as the trace
+    reduction names them."""
+    from chipbench import tracing
+    return {tracing.kernel_name(line.strip())
+            for line in compiled.as_text().splitlines()} - {None}
+
+
+def _metric_kernel(metric: str) -> str:
+    from chipbench.spec import load_module
+    return load_module("metrics", metric).KERNEL
+
+
+def _moved(compiled, sizes) -> list:
+    """Copies and transposes (fusions' bodies included) whose result has
+    one of ``sizes`` elements."""
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
+                in sizes:
+            moved.append(line.strip()[:120])
+    return moved
+
+
+def _cell_serve_step(one_chip, name, B, max_len):
+    """The decode step of a benchmark configuration (its file's sizes and
+    dtypes) as its cell serves it, the cache donated, compiled; with the
+    cache's shapes."""
+    from chipbench import program
+    from chipbench.spec import HERE, load_json
+    cfg = program.model_config(load_json(HERE / "configs" / f"{name}.json"))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,   # noqa: E731
+                                           sharding=one_chip)
+    params = jax.tree_util.tree_map(place, lm.abstract_params(cfg))
+    cache = jax.tree_util.tree_map(place, lm.abstract_cache(cfg, max_len, B))
+    arg = lambda s: jax.ShapeDtypeStruct(s, jnp.int32,         # noqa: E731
+                                         sharding=one_chip)
+    serve = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, arg((B, 1)), arg(())).compile()
+    return cfg, params, serve, {n: c.shape for n, c in cache.items()}
+
+
 @pytest.mark.parametrize("arch,dtype", [
     ("stablelm-1.6b", jnp.bfloat16),
     ("stablelm-1.6b", jnp.float32),
@@ -96,8 +140,6 @@ def test_ssd_chunk_compiles(one_chip):
 def test_ssd_kernel_keeps_the_name_its_roofline_reads(one_chip):
     """The compiled ``ops.ssd`` at mamba2-130m's sizes holds a Pallas call
     that the trace reduction names as ``ssd_scan_roofline`` expects."""
-    from chipbench import tracing
-    from chipbench.spec import load_module
     cfg = get_config("mamba2-130m")
     s = cfg.ssm
     B, S = 8, 2 * s.chunk
@@ -105,9 +147,8 @@ def test_ssd_kernel_keeps_the_name_its_roofline_reads(one_chip):
     args = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
             for shape in ((B, S, nh, hp), (B, S, nh), (nh,), (B, S, ns),
                           (B, S, ns), (nh,))]
-    text = ssd_ops.ssd.lower(*args, chunk=s.chunk).compile().as_text()
-    names = {tracing.kernel_name(line.strip()) for line in text.splitlines()}
-    assert load_module("metrics", "ssd_scan_roofline").KERNEL in names
+    c = ssd_ops.ssd.lower(*args, chunk=s.chunk).compile()
+    assert _metric_kernel("ssd_scan_roofline") in _kernels(c)
 
 
 @pytest.fixture(scope="module")
@@ -145,14 +186,7 @@ def test_stablelm_serve_step_never_copies_the_kv_cache(stablelm_serve_step):
     copies took."""
     c, shape = stablelm_serve_step
     stack = math.prod(shape)
-    sizes = {stack, stack // shape[0]}
-    moved = []
-    for line in c.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
-                     r"(copy|transpose)\(", line)
-        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
-                in sizes:
-            moved.append(line.strip()[:120])
+    moved = _moved(c, {stack, stack // shape[0]})
     assert not moved, moved
     assert c.memory_analysis().temp_size_in_bytes < 3.92 * 2**30
 
@@ -161,23 +195,49 @@ def test_stablelm_serve_step_never_copies_the_kv_cache(stablelm_serve_step):
 def zamba2_cell(one_chip):
     """zamba2-7b as its benchmark cell serves it (the configuration file:
     24 layers, bfloat16 weights; B=8, 3072-token prompts, max_len 3328):
-    its serve step with the cache donated and its prefill step, compiled."""
-    from chipbench import program
-    from chipbench.spec import HERE, load_json
+    its serve step with the cache donated and its prefill step, compiled;
+    with the cache's shapes."""
     from repro.launch.steps import make_prefill_step
-    cfg = program.model_config(load_json(HERE / "configs" / "zamba2-7b.json"))
-    B, S0, max_len = 8, 3072, 3328
-    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,   # noqa: E731
-                                           sharding=one_chip)
-    params = jax.tree_util.tree_map(place, lm.abstract_params(cfg))
-    cache = jax.tree_util.tree_map(place, lm.abstract_cache(cfg, max_len, B))
-    arg = lambda s: jax.ShapeDtypeStruct(s, jnp.int32,         # noqa: E731
-                                         sharding=one_chip)
-    serve = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
-        params, cache, arg((B, 1)), arg(())).compile()
+    B, S0 = 8, 3072
+    cfg, params, serve, shapes = _cell_serve_step(one_chip, "zamba2-7b", B,
+                                                  3328)
     prefill = jax.jit(make_prefill_step(cfg)).lower(
-        params, {"tokens": arg((B, S0))}).compile()
-    return serve, prefill, cache["k"].shape
+        params, {"tokens": jax.ShapeDtypeStruct((B, S0), jnp.int32,
+                                                sharding=one_chip)}).compile()
+    return serve, prefill, shapes
+
+
+@pytest.fixture(scope="module")
+def mamba2_serve_step(one_chip):
+    """mamba2-130m's decode step as ``mamba2-130m.chat-gen`` serves it
+    (B=128, max_len 512, the cache donated), compiled; with the cache's
+    shapes."""
+    _, _, serve, shapes = _cell_serve_step(one_chip, "mamba2-130m", 128, 512)
+    return serve, shapes
+
+
+@pytest.mark.parametrize("cell", ["mamba2_serve_step", "zamba2_cell"])
+def test_serve_step_updates_the_ssm_state_in_place(cell, request):
+    """Decode updates each layer's SSM state where it lies, in the layout
+    the cache stores: no copy or transpose (fusions' bodies included) moves
+    the whole state stack or one layer of it, in any shape. The update is
+    the Pallas call that ``ssd_state_roofline`` counts; the prefill
+    kernel's, which ``ssd_scan_roofline`` counts, is absent."""
+    fixture = request.getfixturevalue(cell)
+    serve, shape = fixture[0], fixture[-1]["ssm"]
+    stack = math.prod(shape)
+    moved = _moved(serve, {stack, stack // shape[0]})
+    assert not moved, moved
+    names = _kernels(serve)
+    assert _metric_kernel("ssd_state_roofline") in names
+    assert _metric_kernel("ssd_scan_roofline") not in names
+
+
+def test_mamba2_serve_step_temporaries_under_1gib(mamba2_serve_step):
+    """Without the stacked copy of the 2.42 GB state that the layer scan
+    used to build, the step's temporaries stay under 1 GiB."""
+    serve, _ = mamba2_serve_step
+    assert serve.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def test_zamba2_steps_fit_one_chip(zamba2_cell):
@@ -197,18 +257,12 @@ def test_zamba2_steps_fit_one_chip(zamba2_cell):
 def test_zamba2_serve_step_never_copies_the_kv_cache(zamba2_cell):
     """Each site writes its token into its K/V rows in place: no copy or
     transpose moves the sites' stack or one site of it."""
-    serve, _, shape = zamba2_cell
-    stack = math.prod(shape)
-    sizes = {stack, stack // shape[0]}
-    moved = []
-    for line in serve.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
-                     r"(copy|transpose)\(", line)
-        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
-                in sizes:
-            moved.append(line.strip()[:120])
+    serve, _, shapes = zamba2_cell
+    stack = math.prod(shapes["k"])
+    moved = _moved(serve, {stack, stack // shapes["k"][0]})
     assert not moved, moved
-    assert "tpu_custom_call" not in serve.as_text()   # decode: no SSD kernel
+    # the prefill kernel's roofline counts prefill calls alone
+    assert _metric_kernel("ssd_scan_roofline") not in _kernels(serve)
 
 
 def test_zamba2_prefill_keeps_the_kernel_name_its_roofline_reads(
@@ -216,9 +270,5 @@ def test_zamba2_prefill_keeps_the_kernel_name_its_roofline_reads(
     """The grouped SSD kernel in zamba2-7b's prefill is the Pallas call
     that ``ssd_scan_roofline.gen`` counts (the reader of
     ``ssd_scan_roofline``)."""
-    from chipbench import tracing
-    from chipbench.spec import load_module
     _, prefill, _ = zamba2_cell
-    names = {tracing.kernel_name(line.strip())
-             for line in prefill.as_text().splitlines()}
-    assert load_module("metrics", "ssd_scan_roofline").KERNEL in names
+    assert _metric_kernel("ssd_scan_roofline") in _kernels(prefill)

@@ -148,3 +148,87 @@ def test_model_mamba_uses_kernel_equivalently(monkeypatch):
     np.testing.assert_allclose(np.asarray(l0, np.float32),
                                np.asarray(l1, np.float32),
                                atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("B,nh,hp,ns,G", [
+    (2, 8, 32, 128, 1),
+    (3, 16, 64, 64, 2),       # two positions a stored row; a block a group
+    (2, 32, 32, 16, 4),       # eight positions a row, four groups
+])
+def test_ssd_state_kernel_matches_the_one_token_recurrence(B, nh, hp, ns,
+                                                           G):
+    """The decode kernel updates layer ``layer`` of the stacked state in
+    place: its y (with the D skip) and new state are ``ssd_decode_step``'s
+    and the one-token chunked SSD's, and every other layer is
+    bit-identical. Head blocks stop at group boundaries: the head count
+    spans G groups, each read by nh/G heads."""
+    from repro.kernels.ssd_scan.ops import ssd_state
+    from repro.models.mamba import ssd_chunked, ssd_decode_step, state_layout
+    L, layer = 3, 1
+    ks = jax.random.split(jax.random.PRNGKey(6), 6)
+    x = jax.random.normal(ks[0], (B, nh, hp)) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, nh)))
+    A_log = jax.random.normal(ks[2], (nh,)) * 0.3
+    B_ = jax.random.normal(ks[3], (B, G, ns)) * 0.5
+    C_ = jax.random.normal(ks[4], (B, G, ns)) * 0.5
+    D_ = jnp.linspace(0.5, 1.5, nh)
+    st = jax.random.normal(ks[5], (L, B, nh, hp, ns)) * 0.2
+    stored = st.reshape((L, B, nh) + state_layout(hp, ns))
+    dA = jnp.exp(dt * -jnp.exp(A_log))
+    y, new = ssd_state(stored, jnp.int32(layer), x * dt[..., None], dA, B_,
+                       C_, interpret=True)
+    y = y + x * D_[None, :, None]
+    new = np.asarray(new).reshape(st.shape)
+    yr, sr = ssd_decode_step(x, dt, A_log, B_, C_, D_, st[layer])
+    one = (lambda t: t[:, None]) if G > 1 else (lambda t: t[:, None, 0])
+    yc, sc = ssd_chunked(x[:, None], dt[:, None], A_log, one(B_), one(C_),
+                         D_, 1, state=st[layer], return_state=True)
+    for want_y, want_st in ((yr, sr), (yc[:, 0], sc)):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(new[layer], np.asarray(want_st),
+                                   atol=2e-6, rtol=2e-6)
+    others = np.arange(L) != layer
+    np.testing.assert_array_equal(new[others], np.asarray(st)[others])
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("arch", ["mamba2-130m", "zamba2-7b"])
+def test_decode_with_the_state_kernel_matches_forward(arch, use_pallas,
+                                                      monkeypatch):
+    """Prefill, then 8 decode steps through the cache reproduce the full
+    forward's logits, whether the SSM state is updated by the Pallas
+    ``ssd_state`` kernel (interpreted on the CPU) or by
+    ``ssd_decode_step``."""
+    import dataclasses
+    import functools
+    from repro.configs import get_smoke_config
+    from repro.kernels.ssd_scan import ops as ssd_ops
+    from repro.launch.steps import make_prefill_step
+    from repro.models import lm
+    # prefill's and decode's kernels compile unless asked for the
+    # interpreter
+    for name in ("ssd", "ssd_state"):
+        monkeypatch.setattr(ssd_ops, name, functools.partial(
+            getattr(ssd_ops, name), interpret=True))
+    cfg = get_smoke_config(arch).replace(compute_dtype="float32",
+                                         use_pallas=use_pallas)
+    if cfg.ssm.ngroups > 1:
+        # 16 heads in 2 groups: the decode kernel's head blocks hold 8
+        cfg = cfg.replace(ssm=dataclasses.replace(cfg.ssm, headdim=8))
+    key = jax.random.PRNGKey(8)
+    params = lm.init_params(cfg, key)
+    S0, S1, B = 24, 32, 2
+    toks = jax.random.randint(key, (B, S1), 0, cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = lm.forward(cfg, params, {"tokens": toks})
+        _, cache = make_prefill_step(cfg)(params, {"tokens": toks[:, :S0]})
+        full = lm.init_cache(cfg, S1, B)
+        cache = {n: full[n].at[tuple(slice(0, s) for s in c.shape)].set(c)
+                 for n, c in cache.items()}
+        for pos in range(S0, S1):
+            lg, cache = lm.decode_step(cfg, params, cache,
+                                       toks[:, pos:pos + 1], jnp.int32(pos))
+            np.testing.assert_allclose(np.asarray(lg),
+                                       np.asarray(want[:, pos]),
+                                       atol=5e-2, rtol=1e-2)
